@@ -20,6 +20,8 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     from . import (fig1_label_distortion, table1_components, table2_overhead,
                    table3_decompress, table4_stream, table5_fixloop,
                    table6_entropy, table7_preserve, fig7_fixed_bound,

@@ -13,9 +13,8 @@ outputs — the flags change execution strategy only.
   --backend B      stencil backend for the fix loops
                    (auto | reference | pallas | pallas_tiled | sharded)
   --devices N      slab-shard fix loops/transforms over an N-device
-                   ('data',) mesh (emulated on CPU hosts; sets
-                   --xla_force_host_platform_device_count before jax
-                   initializes)
+                   ('data',) mesh: N real chips on an accelerator host,
+                   N emulated devices under JAX_PLATFORMS=cpu
   --host-path      force the host byte-codec COMPRESS path (default:
                    device-resident whenever preconditions hold)
   --decode-path P  decompression path: auto | host | device
@@ -25,7 +24,6 @@ outputs — the flags change execution strategy only.
                    its stats line; artifacts stay byte-identical
 """
 import argparse
-import os
 import time
 
 
@@ -38,7 +36,8 @@ def _parse_args():
                          "sharded)")
     ap.add_argument("--devices", type=int, default=0,
                     help="shard the fix loops over an N-device ('data',) "
-                         "mesh (emulated on CPU hosts)")
+                         "mesh (real chips; emulated under "
+                         "JAX_PLATFORMS=cpu)")
     ap.add_argument("--host-path", action="store_true",
                     help="force the host byte-codec path (default: the "
                          "device-resident path whenever its preconditions "
@@ -58,12 +57,8 @@ def _parse_args():
 
 def main():
     args = _parse_args()
-    if args.devices > 1:
-        # must land before jax initializes its backends (imports below)
-        flag = f"--xla_force_host_platform_device_count={args.devices}"
-        if flag not in os.environ.get("XLA_FLAGS", ""):
-            os.environ["XLA_FLAGS"] = \
-                (os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
+    from repro.launch.mesh import request_devices
+    request_devices(args.devices)       # before jax initializes
 
     import numpy as np
     import jax.numpy as jnp
@@ -79,7 +74,8 @@ def main():
     mesh = None
     if args.devices > 1:
         mesh = make_data_mesh(args.devices)
-        print(f"# sharding fix loops over {args.devices} devices "
+        print(f"# sharding fix loops over {args.devices} "
+              f"{mesh.devices.flat[0].platform} devices "
               f"(mesh axes {dict(mesh.shape)})")
     datasets = {
         "molecular": (24, 24, 12),

@@ -432,58 +432,80 @@ class PallasBackend:
         count, so iteration counts match too. ``skipped_slabs``
         accumulates slabs whose group was skipped, summed over
         iterations (the benchmark's worklist-win metric).
+
+        The groups run as one ``lax.scan``: the slab axis is padded by
+        two slabs below and two (plus the last group's shortfall) above,
+        so every group reads a same-shaped window at a traced offset and
+        the two kernels are compiled once, whatever the slab count. The
+        padding lies outside the domain, where the kernels' global
+        coordinates mask it.
         """
         from ..kernels.fixpass import fix_pass_pallas
         n = g0.shape[0]
         wg = max(int(self.worklist_group), 1)
-        groups = tuple((z0, min(z0 + wg, n)) for z0 in range(0, n, wg))
+        n_groups = -(-n // wg)
+        n_pad = n_groups * wg
         interp = self._interpret()
 
-        def tile_step(g, gi):
-            z0, z1 = groups[gi]
-            a, b = max(z0 - 2, 0), min(z1 + 2, n)
-            ext = slice(a, b)
+        def pad(x):
+            return jnp.pad(x, ((2, 2 + n_pad - n),) + ((0, 0),) * (x.ndim - 1))
+
+        topo_p = jax.tree_util.tree_map(pad, topo)
+        sizes = jnp.clip(n - wg * jnp.arange(n_groups, dtype=jnp.int32),
+                         0, wg)
+
+        def tile_step(g_p, gi):
+            z0 = gi * wg                       # padded index of slab z0-2
+
+            def win(x, lo, size):
+                return jax.lax.dynamic_slice_in_dim(x, z0 + lo, size)
+
             masks = self.extrema_masks(
-                g[ext], jax.tree_util.tree_map(lambda x: x[ext], topo),
-                slab_lo=a, n_slabs_total=n)
-            c, d = max(z0 - 1, 0), min(z1 + 1, n)
-            ss = slice(c - a, d - a)
+                win(g_p, 0, wg + 4),
+                jax.tree_util.tree_map(lambda x: win(x, 0, wg + 4), topo_p),
+                slab_lo=z0 - 2, n_slabs_total=n)
+            ss = slice(1, wg + 3)
             g2, src, tgt = fix_pass_pallas(
-                g[c:d], topo.lower[c:d],
+                win(g_p, 1, wg + 2), win(topo_p.lower, 1, wg + 2),
                 masks.self_edit[ss], masks.demote_src[ss],
-                masks.promote_src[ss], masks.up_c_g[ss], topo.dn_c[c:d],
-                interpret=interp, slab_lo=c, n_slabs_total=n)
-            tp = slice(z0 - c, z0 - c + (z1 - z0))
-            return g2[tp], src[tp], tgt[tp]
+                masks.promote_src[ss], masks.up_c_g[ss],
+                win(topo_p.dn_c, 1, wg + 2),
+                interpret=interp, slab_lo=z0 - 1, n_slabs_total=n)
+            return g2[1:wg + 1], src[1:wg + 1], tgt[1:wg + 1]
 
         def body(state):
-            g, it, src, tgt, skipped = state
+            g_p, it, src, tgt, skipped = state
             dirty = tgt > 0
             run_slab = dirty
             for s in (1, 2):        # dilate by the 2-slab stencil radius
                 run_slab = (run_slab
                             | jnp.pad(dirty[s:], (0, s))
                             | jnp.pad(dirty[:-s], (s, 0)))
-            parts_g, parts_s, parts_t = [], [], []
-            for gi, (z0, z1) in enumerate(groups):
-                run = jnp.any(run_slab[z0:z1])
+            run = jnp.pad(run_slab, (0, n_pad - n)).reshape(
+                n_groups, wg).any(axis=1)
+            src_p = jnp.pad(src, (0, n_pad - n))
 
-                def compute(ops, gi=gi):
-                    return tile_step(ops[0], gi)
+            def group(g_next, xs):
+                gi, run_g = xs
 
-                def reuse(ops, z0=z0, z1=z1):
-                    return (jax.lax.slice_in_dim(ops[0], z0, z1),
-                            jax.lax.slice_in_dim(ops[1], z0, z1),
-                            jnp.zeros(z1 - z0, jnp.int32))
+                def compute(g_next):
+                    g2, s2, t2 = tile_step(g_p, gi)
+                    return (jax.lax.dynamic_update_slice_in_dim(
+                        g_next, g2, gi * wg + 2, 0), s2, t2)
 
-                tg, ts, tt = jax.lax.cond(run, compute, reuse, (g, src))
-                parts_g.append(tg)
-                parts_s.append(ts)
-                parts_t.append(tt)
-                skipped = skipped + jnp.where(run, 0, z1 - z0)
-            return (jnp.concatenate(parts_g, axis=0), it + 1,
-                    jnp.concatenate(parts_s, axis=0),
-                    jnp.concatenate(parts_t, axis=0), skipped)
+                def reuse(g_next):
+                    return (g_next,
+                            jax.lax.dynamic_slice_in_dim(src_p, gi * wg, wg),
+                            jnp.zeros(wg, jnp.int32))
+
+                g_next, s2, t2 = jax.lax.cond(run_g, compute, reuse, g_next)
+                return g_next, (s2, t2)
+
+            g_next, (srcs, tgts) = jax.lax.scan(
+                group, g_p, (jnp.arange(n_groups, dtype=jnp.int32), run))
+            skipped = skipped + jnp.sum(jnp.where(run, 0, sizes))
+            return (g_next, it + 1, srcs.reshape(-1)[:n],
+                    tgts.reshape(-1)[:n], skipped)
 
         def cond(state):
             _, it, src, _, _ = state
@@ -491,11 +513,11 @@ class PallasBackend:
 
         # first iteration unconditionally runs every group (tgt
         # sentinel 1s), mirroring the dense loop's step-then-while shape
-        state0 = (g0, jnp.int32(0), jnp.zeros(n, jnp.int32),
+        state0 = (pad(g0), jnp.int32(0), jnp.zeros(n, jnp.int32),
                   jnp.ones(n, jnp.int32), jnp.int32(0))
-        g, it, src, tgt, skipped = jax.lax.while_loop(cond, body,
-                                                      body(state0))
-        return g, it, jnp.sum(src) == 0, skipped
+        g_p, it, src, tgt, skipped = jax.lax.while_loop(cond, body,
+                                                        body(state0))
+        return g_p[2:2 + n], it, jnp.sum(src) == 0, skipped
 
 
 # ---------------------------------------------------------------------------

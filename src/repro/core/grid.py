@@ -86,6 +86,10 @@ def _sos_argbest(vals: jnp.ndarray, idxs: jnp.ndarray, *, ascending: bool):
     time per stencil neighbor, >10^5 s for the full Freudenthal stencil).
     Reductions are emitted as loops, keeping codegen linear; results are
     bitwise identical to the scan.
+
+    The winning slot is an int32 min over slot numbers, not an argmax
+    over a bool stack: Mosaic lowers argmax for float32 only. A slot set
+    with no winner (NaN candidates) yields slot 0, as argmax did.
     """
     if ascending:
         v_best = jnp.max(vals, axis=0)
@@ -95,8 +99,60 @@ def _sos_argbest(vals: jnp.ndarray, idxs: jnp.ndarray, *, ascending: bool):
         v_best = jnp.min(vals, axis=0)
         i_fill = jnp.int32(np.iinfo(np.int32).max)
         i_best = jnp.min(jnp.where(vals == v_best, idxs, i_fill), axis=0)
+    n = vals.shape[0]
     win = (vals == v_best) & (idxs == i_best)
-    return jnp.argmax(win, axis=0).astype(jnp.int32)
+    slot_ids = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 0)
+    slot = jnp.min(jnp.where(win, slot_ids, jnp.int32(n)), axis=0)
+    return jnp.where(slot == n, jnp.int32(0), slot)
+
+
+#: voxels per z-chunk of a 3D ``steepest_dirs``: the stacked stencil
+#: candidates take 15 x 8 bytes per voxel, so one chunk's stack stays
+#: near 0.5 GiB instead of growing with the field (a 512^3 field's whole
+#: stack would need 23 GB)
+_CHUNK_VOXELS = 1 << 22
+
+
+def _fills(dtype, ascending: bool):
+    """(value, linear index) of an off-domain neighbor: it never wins."""
+    if ascending:
+        return jnp.asarray(-jnp.inf, dtype), jnp.int32(-1)
+    return jnp.asarray(jnp.inf, dtype), jnp.int32(np.iinfo(np.int32).max)
+
+
+def _codes(f: jnp.ndarray, lin: jnp.ndarray, *, ascending: bool
+           ) -> jnp.ndarray:
+    """Steepest-neighbor codes of every vertex of ``f`` (linear indices
+    ``lin``), with off-array neighbors filled as off-domain ones."""
+    offs = offsets_for(f.ndim)
+    fill_v, fill_i = _fills(f.dtype, ascending)
+    vals = jnp.stack([f] + [shift(f, o, fill_v) for o in offs])
+    idxs = jnp.stack([lin] + [shift(lin, o, fill_i) for o in offs])
+    slot = _sos_argbest(vals, idxs, ascending=ascending)
+    return jnp.where(slot == 0, jnp.int32(self_code(f.ndim)), slot - 1)
+
+
+def _codes_chunked(f: jnp.ndarray, lin: jnp.ndarray, chunk: int, *,
+                   ascending: bool) -> jnp.ndarray:
+    """``_codes`` over z-chunks of ``chunk`` slabs, one after another.
+    Each chunk sees a 1-slab window margin; margins past the domain
+    carry the off-domain fills, so every kept slab's candidates — and
+    so its codes — equal the whole-field computation's bit for bit."""
+    n = f.shape[0]
+    n_chunks = -(-n // chunk)
+    pad = ((1, 1 + n_chunks * chunk - n),) + ((0, 0),) * (f.ndim - 1)
+    fill_v, fill_i = _fills(f.dtype, ascending)
+    fp = jnp.pad(f, pad, constant_values=fill_v)
+    lp = jnp.pad(lin, pad, constant_values=fill_i)
+
+    def one(c):
+        z0 = c * chunk
+        w = jax.lax.dynamic_slice_in_dim(fp, z0, chunk + 2)
+        wl = jax.lax.dynamic_slice_in_dim(lp, z0, chunk + 2)
+        return _codes(w, wl, ascending=ascending)[1:-1]
+
+    out = jax.lax.map(one, jnp.arange(n_chunks, dtype=jnp.int32))
+    return out.reshape((n_chunks * chunk,) + f.shape[1:])[:n]
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -111,25 +167,17 @@ def steepest_dirs(f: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     This is the paper's dominant component ('updating directions', ~80% of
     CPU time, Table 1) fused with its 'find critical points' pass. Slot 0
     of the stacked candidates is the vertex itself, so slot k+1 is stencil
-    code k and slot 0 winning means 'extremum'.
+    code k and slot 0 winning means 'extremum'. 3D fields larger than
+    ``_CHUNK_VOXELS`` run in z-chunks (``_codes_chunked``), which bounds
+    the candidate stack's memory; the codes are the same.
     """
-    offs = offsets_for(f.ndim)
-    sc = jnp.int32(self_code(f.ndim))
     lin = linear_index(f.shape)
-    neg_inf = jnp.asarray(-jnp.inf, f.dtype)
-    pos_inf = jnp.asarray(jnp.inf, f.dtype)
-
-    up_vals = jnp.stack([f] + [shift(f, o, neg_inf) for o in offs])
-    up_idxs = jnp.stack([lin] + [shift(lin, o, jnp.int32(-1)) for o in offs])
-    slot_up = _sos_argbest(up_vals, up_idxs, ascending=True)
-    up_c = jnp.where(slot_up == 0, sc, slot_up - 1)
-
-    i_max = jnp.int32(np.iinfo(np.int32).max)
-    dn_vals = jnp.stack([f] + [shift(f, o, pos_inf) for o in offs])
-    dn_idxs = jnp.stack([lin] + [shift(lin, o, i_max) for o in offs])
-    slot_dn = _sos_argbest(dn_vals, dn_idxs, ascending=False)
-    dn_c = jnp.where(slot_dn == 0, sc, slot_dn - 1)
-    return up_c, dn_c
+    plane = int(np.prod(f.shape[1:]))
+    chunk = max(1, _CHUNK_VOXELS // plane)
+    if f.ndim != 3 or f.shape[0] <= chunk:
+        return _codes(f, lin, ascending=True), _codes(f, lin, ascending=False)
+    return (_codes_chunked(f, lin, chunk, ascending=True),
+            _codes_chunked(f, lin, chunk, ascending=False))
 
 
 def gather_dir(x: jnp.ndarray, code: jnp.ndarray) -> jnp.ndarray:

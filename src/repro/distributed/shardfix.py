@@ -69,7 +69,6 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from ..core.backend import register_backend
@@ -780,10 +779,10 @@ def sharded_fix(g0: jnp.ndarray, topo, mesh: Mesh, *, max_iters: int = 512,
         return out[0], out[1], out[2]
 
     spec = plan.spec()
-    g, iters, viol = shard_map(
+    g, iters, viol = jax.shard_map(
         spmd, mesh=mesh, in_specs=(spec, spec),
         out_specs=(spec, PartitionSpec(), PartitionSpec()),
-        check_rep=False)(g_p, topo_p)
+        check_vma=False)(g_p, topo_p)
     return _unpad(g, plan), iters, viol == 0
 
 
@@ -862,9 +861,9 @@ def time_step_parts(g0: jnp.ndarray, topo, mesh: Mesh, *,
             return g2, viol
 
         fn = spmd if use_overlap else plain
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=mesh, in_specs=(spec, spec),
-            out_specs=(spec, PartitionSpec()), check_rep=False))
+            out_specs=(spec, PartitionSpec()), check_vma=False))
 
     parts = ("interior", "exchange", "full") if use_overlap else ("full",)
     res: Dict[str, float] = {}
@@ -929,8 +928,8 @@ def sharded_transform(f: jnp.ndarray, step, mesh: Mesh, *,
         return r_ext[drop]
 
     spec = plan.spec()
-    r = shard_map(spmd, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                  check_rep=False)(f_p)
+    r = jax.shard_map(spmd, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                      check_vma=False)(f_p)
     return _unpad(r, plan)
 
 
@@ -978,9 +977,9 @@ def sharded_scatter_edits(f_hat: jnp.ndarray, idx, val, mesh: Mesh, *,
     idx_dev = typed_operand(idx, jnp.int32)
     val_dev = (val if isinstance(val, jnp.ndarray)
                else typed_operand(val, np.asarray(val).dtype))
-    out = shard_map(spmd, mesh=mesh,
-                    in_specs=(spec, PartitionSpec(), PartitionSpec()),
-                    out_specs=spec, check_rep=False)(
+    out = jax.shard_map(spmd, mesh=mesh,
+                        in_specs=(spec, PartitionSpec(), PartitionSpec()),
+                        out_specs=spec, check_vma=False)(
         f_p, idx_dev, val_dev)
     return _unpad(out, plan)
 
@@ -1018,8 +1017,8 @@ def sharded_reconstruct(r: jnp.ndarray, step, dtype, mesh: Mesh, *,
         return q.astype(dtype) * step_arr
 
     spec = plan.spec()
-    out = shard_map(spmd, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                    check_rep=False)(r_p)
+    out = jax.shard_map(spmd, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                        check_vma=False)(r_p)
     return _unpad(out, plan)
 
 
@@ -1103,9 +1102,9 @@ class ShardedBackend:
             return g2, viol
 
         spec = plan.spec()
-        g2, viol = shard_map(
+        g2, viol = jax.shard_map(
             spmd, mesh=be.mesh, in_specs=(spec, spec),
-            out_specs=(spec, PartitionSpec()), check_rep=False)(g_p, topo_p)
+            out_specs=(spec, PartitionSpec()), check_vma=False)(g_p, topo_p)
         return _unpad(g2, plan), viol
 
     # -- full-loop fast path consumed by fixes.fused_fix ---------------

@@ -7,13 +7,17 @@ Each vertex j decreases to (g_j + lower_j)/2 iff
   * a stencil neighbor i has demote_src[i] and up_code_g[i] pointing at j, or
   * a stencil neighbor i has promote_src[i] and dn_code_f[i] pointing at j.
 
-Same slab halo layout as the extrema kernel (3D: z-slabs; 2D: y-rows),
-including the global-coordinate ``slab_lo``/``n_slabs_total`` placement
-for tiled execution. Also emits per-slab violation (fix-source) and
-edit-target counts: the source counts are the paper's lock-free
-work-queue height turned into a reduction, and the target counts are the
-dirty-slab bitmap the worklist drivers (DESIGN.md §7) use to skip slabs
-whose neighborhood did not change last pass."""
+Same slab layout as the extrema kernel (3D: z-slabs; 2D: row blocks
+with a row halo), including the global-coordinate placement for tiled
+execution. Also emits per-slab violation (fix-source) and edit-target
+counts — one per z-slab in 3D, one per row in 2D: the source counts are
+the paper's lock-free work-queue height turned into a reduction, and
+the target counts are the dirty-slab bitmap the worklist drivers
+(DESIGN.md §7) use to skip slabs whose neighborhood did not change last
+pass. The counts leave each program as an (S, 1) column block (S slabs
+or rows per program), which meets Mosaic's tiling rule at any S; masks
+are combined as int32 0/1 values, since Mosaic cannot truncate to
+booleans in every position."""
 from __future__ import annotations
 
 import functools
@@ -22,9 +26,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .extrema import (_axis_total, _shift2d, default_interpret,
-                      origin_operand, origin_spec, slab_block_specs,
-                      slab_offsets)
+from .extrema import (TEMP_PLANES, default_interpret, edge_masked,
+                      global_extents, load_window, neighbor, origin_operand,
+                      origin_spec, program_coords, slab_layout, slab_offsets,
+                      vmem_params)
 
 # code k is stored at i; i targets j = i + off_k. From j's view the source
 # sits at -off_k and must carry code k.
@@ -33,58 +38,48 @@ from .extrema import (_axis_total, _shift2d, default_interpret,
 def _kernel(origin_c, g_c, low_c, self_c,
             dem_m, dem_c, dem_p, pro_m, pro_c, pro_p,
             upg_m, upg_c, upg_p, dnf_m, dnf_c, dnf_p,
-            g_out, viol_out, tgt_out, *, N, NY, NX, P, X, offs):
-    z = origin_c[0, 0] + pl.program_id(0)
-    yg = origin_c[0, 1] + jax.lax.broadcasted_iota(jnp.int32, (P, X), 0)
-    xg = origin_c[0, 2] + jax.lax.broadcasted_iota(jnp.int32, (P, X), 1)
+            g_out, viol_out, tgt_out, *, N, NY, NX, lay, offs):
+    z, yg, xg = program_coords(origin_c, lay)
 
     def plane(ref):
-        return ref[...].reshape(P, X)
+        return ref[...].reshape(lay.P, lay.X)
 
-    def pulled(src_slabs, code_slabs):
-        out = jnp.zeros((P, X), bool)
+    def pulled(src_win, code_win):
+        out = jnp.zeros((lay.P, lay.X), jnp.int32)
         for k, (ds, dy, dx) in enumerate(offs):
-            sds, sdy, sdx = -ds, -dy, -dx
-            src = src_slabs[sds + 1]
-            cod = code_slabs[sds + 1]
-            m = _shift2d(src, -dy, -dx, 0) != 0
-            c = _shift2d(cod, -dy, -dx, -1)
+            m = neighbor(src_win, lay, -ds, -dy, -dx, 0)
+            c = neighbor(code_win, lay, -ds, -dy, -dx, -1)
             # a pull source must lie inside the real domain, checked in
-            # GLOBAL coordinates on all three axes: at a block seam the
-            # shifted value is ghost data (valid), at a true domain edge
-            # the _shift2d zero-fill already cleared m and the global
-            # mask below is a no-op — identical either way
-            if sds == -1:
-                m = jnp.where(z == 0, False, m)
-            elif sds == 1:
-                m = jnp.where(z == N - 1, False, m)
-            if sdy == -1:
-                m = jnp.where(yg == 0, False, m)
-            elif sdy == 1:
-                m = jnp.where(yg == NY - 1, False, m)
-            if sdx == -1:
-                m = jnp.where(xg == 0, False, m)
-            elif sdx == 1:
-                m = jnp.where(xg == NX - 1, False, m)
-            out = out | (m & (c == k))
+            # GLOBAL coordinates: at a block seam the shifted value is
+            # ghost data (valid), at a true domain edge the zero fill
+            # already cleared m and the mask is a no-op
+            m = edge_masked(m, -ds, -dy, -dx, z, N, yg, xg, NY, NX, 0)
+            out = out | jnp.where((m != 0) & (c == k), 1, 0)
         return out
 
-    dem = (plane(dem_m), plane(dem_c), plane(dem_p))
-    pro = (plane(pro_m), plane(pro_c), plane(pro_p))
-    upg = (plane(upg_m), plane(upg_c), plane(upg_p))
-    dnf = (plane(dnf_m), plane(dnf_c), plane(dnf_p))
+    dem = load_window((dem_m, dem_c, dem_p), lay)
+    pro = load_window((pro_m, pro_c, pro_p), lay)
+    upg = load_window((upg_m, upg_c, upg_p), lay)
+    dnf = load_window((dnf_m, dnf_c, dnf_p), lay)
 
     self_p = plane(self_c)
-    target = ((self_p != 0)
-              | pulled(dem, upg)
-              | pulled(pro, dnf))
+    target = (jnp.where(self_p != 0, 1, 0)
+              | pulled(dem, upg) | pulled(pro, dnf))
     g = plane(g_c)
     low = plane(low_c)
     new = jnp.maximum((g + low) * jnp.asarray(0.5, g.dtype), low)
-    g_out[...] = jnp.where(target, new, g).reshape(g_out.shape)
-    viol = jnp.sum(self_p) + jnp.sum(dem[1]) + jnp.sum(pro[1])
-    viol_out[0, 0] = viol.astype(jnp.int32)
-    tgt_out[0, 0] = jnp.sum(target).astype(jnp.int32)
+    g_out[...] = jnp.where(target != 0, new, g).reshape(g_out.shape)
+
+    def counts(x):
+        """Per-slab (3D) or per-row (2D) sums of ``x``, as an (S, 1)
+        column."""
+        rows = jnp.sum(x, axis=1, keepdims=True)
+        if lay.ndim == 3:
+            rows = jnp.sum(rows, axis=0, keepdims=True)
+        return rows.reshape(viol_out.shape)
+
+    viol_out[...] = counts(self_p + dem[1] + pro[1])
+    tgt_out[...] = counts(target)
 
 
 def fix_pass_pallas(g, lower, self_edit, demote_src, promote_src,
@@ -94,47 +89,49 @@ def fix_pass_pallas(g, lower, self_edit, demote_src, promote_src,
                     n_rows_total: int | None = None,
                     n_cols_total: int | None = None):
     """Apply one fused fix pass. All inputs (Z,Y,X) or (Y,X); masks int32
-    0/1. Returns (g_next of g's shape/dtype, viol (n_slabs,) int32
-    per-slab fix-SOURCE counts, tgt (n_slabs,) int32 per-slab edit-TARGET
-    counts). ``viol`` drives convergence (0 sources everywhere == done);
-    ``tgt`` feeds the dirty-slab worklists (DESIGN.md §7): a slab whose
-    targets were 0 last pass — and whose 2-slab neighborhood's were too —
-    produces bitwise-identical masks this pass and can be skipped.
+    0/1. Returns (g_next of g's shape/dtype, viol (n,) int32 per-slab
+    fix-SOURCE counts, tgt (n,) int32 per-slab edit-TARGET counts), n
+    being the leading extent (z-slabs in 3D, rows in 2D). ``viol``
+    drives convergence (0 sources everywhere == done); ``tgt`` feeds the
+    dirty-slab worklists (DESIGN.md §7): a slab whose targets were 0
+    last pass — and whose 2-slab neighborhood's were too — produces
+    bitwise-identical masks this pass and can be skipped.
     ``slab_lo``/``n_slabs_total`` and ``row_lo``/``col_lo`` with
     ``n_rows_total``/``n_cols_total`` as in the extrema kernel (offsets
     may be traced; the matching total is then required)."""
     if interpret is None:
         interpret = default_interpret()
-    if g.ndim == 3:
-        n_local, P, X = g.shape
-    elif g.ndim == 2:
-        n_local, X = g.shape
-        P = 1
-    else:
+    if g.ndim not in (2, 3):
         raise ValueError(f"fix kernel supports 2D/3D, got shape {g.shape}")
-    N = _axis_total(n_slabs_total, slab_lo, n_local, "slab")
-    NY = _axis_total(n_rows_total, row_lo, P, "row")
-    NX = _axis_total(n_cols_total, col_lo, X, "col")
-
-    halo, center = slab_block_specs(g.ndim, n_local, P, X)
-    count_spec = pl.BlockSpec((1, 1), lambda z: (z, 0))
-    count_shape = jax.ShapeDtypeStruct((n_local, 1), jnp.int32)
+    N, NY, NX = global_extents(g.shape, slab_lo, n_slabs_total, row_lo,
+                               n_rows_total, col_lo, n_cols_total)
+    lay = slab_layout(g.shape)
+    halo, center = list(lay.halo), lay.center
+    n = g.shape[0]
+    count_spec = pl.BlockSpec((1, lay.S, 1), lambda i: (i, 0, 0))
+    count_shape = jax.ShapeDtypeStruct((lay.grid, lay.S, 1), jnp.int32)
     out_specs = [center, count_spec, count_spec]
     out_shape = [jax.ShapeDtypeStruct(g.shape, g.dtype),
                  count_shape, count_shape]
-    kern = functools.partial(_kernel, N=N, NY=NY, NX=NX, P=P, X=X,
+    kern = functools.partial(_kernel, N=N, NY=NY, NX=NX, lay=lay,
                              offs=slab_offsets(g.ndim))
+    nbytes = (lay.block_bytes(g.dtype.itemsize, halo=0, center=3)
+              + lay.block_bytes(4, halo=4, center=1)
+              + 2 * lay.S * 128 * 4)
+    temps = TEMP_PLANES["fixpass", g.ndim] * lay.plane_bytes(4)
     g2, viol, tgt = pl.pallas_call(
         kern,
-        grid=(n_local,),
+        grid=(lay.grid,),
         in_specs=([origin_spec(), center, center, center]
                   + halo + halo + halo + halo),
         out_specs=out_specs,
         out_shape=out_shape,
+        compiler_params=(None if interpret
+                         else vmem_params(nbytes, temps, "fix-pass kernel")),
         interpret=interpret,
     )(origin_operand(slab_lo, row_lo, col_lo), g, lower, self_edit,
       demote_src, demote_src, demote_src,
       promote_src, promote_src, promote_src,
       up_code_g, up_code_g, up_code_g,
       dn_code_f, dn_code_f, dn_code_f)
-    return g2, viol[:, 0], tgt[:, 0]
+    return g2, viol.reshape(-1)[:n], tgt.reshape(-1)[:n]

@@ -25,10 +25,11 @@ embarrassingly parallel, no byte-sequential state):
 Three bitwise-identical implementations share this layout contract:
 
 * ``pack_codes_pallas`` / ``unpack_codes_pallas`` — the production
-  kernels: one grid program per chunk computes the chunk's bit width
-  and its 32 transposed planes with static loops and 2D iotas (VPU
-  vector ops; blocks are (1, CHUNK) so the lane dimension stays a
-  multiple of 128). The offset scan + compaction scatter stay XLA-level
+  kernels: each grid program takes a block of chunks, every chunk a
+  (32, CHUNK/32) int32 tile, and computes their bit widths and 32
+  transposed planes with static loops and sublane sums (VPU vector
+  ops; the blocks span their last two dimensions, as Mosaic's tiling
+  rule asks). The offset scan + compaction scatter stay XLA-level
   around the kernel — a hand-rolled Pallas scan would only re-derive
   ``int32_cumsum``.
 * ``pack_codes_jnp`` / ``unpack_codes_jnp`` — pure-jnp twins (the
@@ -218,30 +219,59 @@ def unpack_codes_jnp(words: jnp.ndarray, bits: jnp.ndarray,
 # pallas kernels (production path; one grid program per chunk)
 # ---------------------------------------------------------------------------
 
-def _pack_kernel(u_ref, dense_ref, bits_ref, *, wpp: int):
-    u = u_ref[...].reshape(wpp, 32)
-    maxu = jnp.max(u)
-    bits_ref[0, 0] = (jnp.uint32(32) - jax.lax.clz(maxu)).astype(jnp.int32)
-    t = jax.lax.broadcasted_iota(jnp.uint32, (wpp, 32), 1)
-    planes = [jnp.sum(((u >> jnp.uint32(k)) & jnp.uint32(1)) << t,
-                      axis=1, dtype=jnp.uint32) for k in range(32)]
-    dense_ref[...] = jnp.stack(planes, axis=0).reshape(1, 32 * wpp)
+#: chunks per pallas grid program (a multiple of 8: the bit-width column
+#: block is (CHUNKS_PER_BLOCK, 1))
+CHUNKS_PER_BLOCK = 64
 
 
-def _unpack_kernel(dense_ref, u_ref, *, wpp: int):
-    d3 = dense_ref[...].reshape(32, wpp)
-    t = jax.lax.broadcasted_iota(jnp.uint32, (wpp, 32), 1)
-    u = jnp.zeros((wpp, 32), jnp.uint32)
+def _block_chunks(n_chunks: int) -> int:
+    """Chunks per grid program: ``CHUNKS_PER_BLOCK``, or ``n_chunks``
+    rounded up to a multiple of 8 for short streams."""
+    return min(CHUNKS_PER_BLOCK, -(-n_chunks // 8) * 8)
+
+
+def _pack_kernel(u_ref, dense_ref, bits_ref):
+    """One block of chunks, each a (32, wpp) int32 tile holding code
+    ``m*32 + t`` at [t, m]: plane k word m gathers bit k of the chunk's
+    codes m*32..m*32+31, a sum over the tile's sublanes (the bits are
+    disjoint, so the int32 sum is their OR). The bit width is one past
+    the highest plane with a set bit."""
+    u = u_ref[...]
+    G = u.shape[0]
+    t = jax.lax.broadcasted_iota(jnp.int32, u.shape, 1)
+    bits = jnp.zeros((G, 1), jnp.int32)
     for k in range(32):
-        u = u | (((d3[k][:, None] >> t) & jnp.uint32(1)) << jnp.uint32(k))
-    u_ref[...] = u.reshape(1, 32 * wpp)
+        plane = jnp.sum(((u >> k) & 1) << t, axis=1)
+        dense_ref[:, k, :] = plane
+        nz = jnp.max(jnp.where(plane != 0, 1, 0), axis=1, keepdims=True)
+        bits = jnp.maximum(bits, nz * (k + 1))
+    bits_ref[...] = bits
+
+
+def _unpack_kernel(dense_ref, u_ref):
+    """Inverse transpose of ``_pack_kernel``: code (t, m) of a chunk is
+    the sum over planes k of bit t of word (k, m), shifted to bit k."""
+    d = dense_ref[...]
+    k = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+    for t in range(32):
+        u_ref[:, t, :] = jnp.sum(((d >> t) & 1) << k, axis=1)
+
+
+def _to_tiles(flat_u32: jnp.ndarray, n_chunks: int, n_pad_chunks: int,
+              wpp: int) -> jnp.ndarray:
+    """Zigzagged codes -> (n_pad_chunks, 32, wpp) int32 tiles, code
+    ``m*32 + t`` of each chunk at [t, m]; padding chunks are zero."""
+    u = jax.lax.bitcast_convert_type(flat_u32, jnp.int32)
+    u = u.reshape(n_chunks, wpp, 32).transpose(0, 2, 1)
+    return jnp.pad(u, ((0, n_pad_chunks - n_chunks), (0, 0), (0, 0)))
 
 
 def pack_codes_pallas(r: jnp.ndarray, chunk: int = CHUNK, *,
                       interpret: Optional[bool] = None):
     """``pack_codes_jnp`` with the per-chunk plane transpose running as
-    a Pallas kernel (grid over chunks, (1, chunk) uint32 blocks — lane
-    dimension a multiple of 128). The offset prefix scan and the
+    a Pallas kernel (grid over blocks of ``CHUNKS_PER_BLOCK`` chunks,
+    each chunk a (32, chunk/32) tile, so every block is the full extent
+    of its last two dimensions). The offset prefix scan and the
     compaction scatter stay XLA-level around the kernel. Bitwise
     identical to the jnp and host codecs. The whole composition runs
     jitted so its scalar constants bake in at trace time (eager
@@ -260,19 +290,24 @@ def _pack_codes_pallas_jit(r: jnp.ndarray, chunk: int, interpret: bool):
         return (jnp.zeros((0,), jnp.uint32), jnp.zeros((0,), jnp.int32),
                 jnp.int32(0))
     flat = jnp.pad(r.reshape(-1).astype(jnp.int32), (0, n_pad - n))
-    u2 = _zigzag_jnp(flat).reshape(n_chunks, chunk)
+    G = _block_chunks(n_chunks)
+    n_blk = -(-n_chunks // G)
+    tiles = _to_tiles(_zigzag_jnp(flat), n_chunks, n_blk * G, wpp)
     dense, bits = pl.pallas_call(
-        functools.partial(_pack_kernel, wpp=wpp),
-        grid=(n_chunks,),
-        in_specs=[pl.BlockSpec((1, chunk), lambda c: (c, 0))],
-        out_specs=[pl.BlockSpec((1, chunk), lambda c: (c, 0)),
-                   pl.BlockSpec((1, 1), lambda c: (c, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n_chunks, chunk), jnp.uint32),
-                   jax.ShapeDtypeStruct((n_chunks, 1), jnp.int32)],
+        _pack_kernel,
+        grid=(n_blk,),
+        in_specs=[pl.BlockSpec((G, 32, wpp), lambda c: (c, 0, 0))],
+        out_specs=[pl.BlockSpec((G, 32, wpp), lambda c: (c, 0, 0)),
+                   pl.BlockSpec((G, 1), lambda c: (c, 0))],
+        out_shape=[jax.ShapeDtypeStruct(tiles.shape, jnp.int32),
+                   jax.ShapeDtypeStruct((n_blk * G, 1), jnp.int32)],
         interpret=interpret,
-    )(u2)
-    words, n_words = _compact_jnp(dense, bits.reshape(-1), wpp)
-    return words, bits.reshape(-1), n_words
+    )(tiles)
+    dense = jax.lax.bitcast_convert_type(
+        dense[:n_chunks].reshape(n_chunks, 32 * wpp), jnp.uint32)
+    bits = bits[:n_chunks, 0]
+    words, n_words = _compact_jnp(dense, bits, wpp)
+    return words, bits, n_words
 
 
 def unpack_codes_pallas(words: jnp.ndarray, bits: jnp.ndarray,
@@ -299,15 +334,22 @@ def _unpack_codes_pallas_jit(words: jnp.ndarray, bits: jnp.ndarray,
         return jnp.zeros(shape, jnp.int32)
     dense = _expand_jnp(words.astype(jnp.uint32), bits.astype(jnp.int32),
                         wpp)
-    u2 = pl.pallas_call(
-        functools.partial(_unpack_kernel, wpp=wpp),
-        grid=(n_chunks,),
-        in_specs=[pl.BlockSpec((1, chunk), lambda c: (c, 0))],
-        out_specs=pl.BlockSpec((1, chunk), lambda c: (c, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_chunks, chunk), jnp.uint32),
+    G = _block_chunks(n_chunks)
+    n_blk = -(-n_chunks // G)
+    d3 = jax.lax.bitcast_convert_type(dense, jnp.int32).reshape(
+        n_chunks, 32, wpp)
+    d3 = jnp.pad(d3, ((0, n_blk * G - n_chunks), (0, 0), (0, 0)))
+    u = pl.pallas_call(
+        _unpack_kernel,
+        grid=(n_blk,),
+        in_specs=[pl.BlockSpec((G, 32, wpp), lambda c: (c, 0, 0))],
+        out_specs=pl.BlockSpec((G, 32, wpp), lambda c: (c, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(d3.shape, jnp.int32),
         interpret=interpret,
-    )(dense)
-    return _unzigzag_jnp(u2.reshape(-1))[:n].reshape(shape)
+    )(d3)
+    u = jax.lax.bitcast_convert_type(
+        u[:n_chunks].transpose(0, 2, 1).reshape(-1), jnp.uint32)
+    return _unzigzag_jnp(u)[:n].reshape(shape)
 
 
 # ---------------------------------------------------------------------------

@@ -48,6 +48,23 @@ def make_host_mesh() -> jax.sharding.Mesh:
     return jax.make_mesh((1, 1), ("data", "model"))
 
 
+def request_devices(n_devices: int) -> None:
+    """Prepare an ``n_devices`` mesh; call before jax initializes.
+
+    On a CPU run (``JAX_PLATFORMS=cpu``) this asks XLA's host platform
+    for ``n_devices`` emulated devices. Anywhere else the mesh takes the
+    real accelerators jax finds — one per chip — and the host-platform
+    flag would mean nothing, so it is left unset."""
+    if n_devices <= 1:
+        return
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        return
+    flag = f"--xla_force_host_platform_device_count={n_devices}"
+    if flag not in os.environ.get("XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = \
+            (os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
+
+
 def make_data_mesh(n_devices: int | None = None) -> jax.sharding.Mesh:
     """One-axis ('data',) mesh over the first ``n_devices`` local devices
     (default: all). This is the axis the slab-sharded MSz fix loop
@@ -60,8 +77,8 @@ def make_data_mesh(n_devices: int | None = None) -> jax.sharding.Mesh:
     if not 1 <= n <= len(devs):
         raise ValueError(
             f"requested a {n}-device data mesh but {len(devs)} device(s) "
-            "are available (set --xla_force_host_platform_device_count "
-            "before jax initializes to emulate more)")
+            "are available (on CPU, request_devices() before jax "
+            "initializes emulates more)")
     return jax.sharding.Mesh(np.asarray(devs[:n]), ("data",))
 
 

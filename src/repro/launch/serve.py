@@ -10,7 +10,8 @@ Generates a stream of synthetic scalar fields (mixed shapes/bounds when
 into batched device dispatches, entropy coding overlapped on worker
 threads — then round-trips every artifact through the decompress stream.
 ``--devices N`` serves stream members slab-sharded over an N-device
-('data',) mesh (emulated on CPU hosts); ``--stats-port P`` exposes the
+('data',) mesh: N real chips on an accelerator host, N emulated
+devices under ``JAX_PLATFORMS=cpu``; ``--stats-port P`` exposes the
 live stats document at ``http://127.0.0.1:P/stats`` while the run is in
 flight. ``--verify`` checks exact MSS preservation and byte-identity
 against the one-shot pipeline on every request.
@@ -21,7 +22,6 @@ The LM serving launcher this module used to hold lives at
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 
@@ -44,7 +44,8 @@ def _parse_args(argv=None):
                          "pallas_tiled | sharded)")
     ap.add_argument("--devices", type=int, default=0,
                     help="serve stream members slab-sharded over an "
-                         "N-device ('data',) mesh (emulated on CPU hosts)")
+                         "N-device ('data',) mesh (real chips; emulated "
+                         "under JAX_PLATFORMS=cpu)")
     ap.add_argument("--mixed", action="store_true",
                     help="mix a second field shape and per-request bounds "
                          "into the traffic (exercises per-spec batching)")
@@ -62,18 +63,17 @@ def _parse_args(argv=None):
 
 def main(argv=None):
     args = _parse_args(argv)
-    if args.devices > 1:
-        # must land before jax initializes its backends (imports below)
-        flag = f"--xla_force_host_platform_device_count={args.devices}"
-        if flag not in os.environ.get("XLA_FLAGS", ""):
-            os.environ["XLA_FLAGS"] = \
-                (os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
+    from repro.launch.mesh import request_devices
+    request_devices(args.devices)       # before jax initializes
     if args.smoke:
         args.fields = min(args.fields, 8)
         args.shape = "12,12,12"
         args.verify = True
 
     import numpy as np
+
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     from repro.compress import compress_preserving_mss
     from repro.core import verify_preservation
@@ -86,7 +86,8 @@ def main(argv=None):
     mesh = None
     if args.devices > 1:
         mesh = make_data_mesh(args.devices)
-        print(f"# serving over {args.devices} devices "
+        print(f"# serving over {args.devices} "
+              f"{mesh.devices.flat[0].platform} devices "
               f"(mesh axes {dict(mesh.shape)})")
 
     shapes = [shape] * args.fields

@@ -94,7 +94,7 @@ def test_device_host_codec_parity_ties_plateaus(shape):
 def test_device_host_codec_parity_f64(shape):
     """f64 parity needs f64 device arithmetic — run the jit path under
     x64 mode (the device pipeline only auto-selects f64 when x64 is on)."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     rng = np.random.default_rng(8)
     f = rng.normal(size=shape)
     assert f.dtype == np.float64

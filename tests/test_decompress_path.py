@@ -79,7 +79,7 @@ def test_decode_sharded_bitwise_identical(shape, n_dev):
 
 
 def test_decode_f64_under_x64():
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     f, xi = _case((12, 10, 9))
     f = f.astype(np.float64)
     with enable_x64():
@@ -110,7 +110,7 @@ def test_decode_range_check_falls_back():
     must be caught by the decoded-stream check, not silently wrapped.
     Pipeline-produced artifacts only reach this state as f64 (an f32
     field meeting its bound has max|f|/xi < 2^24 < 2^28)."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     rng = np.random.default_rng(0)
     f = 1e7 * (1 + 0.1 * rng.normal(size=(10, 12)))    # f64
     xi = 1e-4      # max|f|/xi ~ 1e11 >> 2^28: int64 host codec only

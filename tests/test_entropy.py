@@ -153,7 +153,7 @@ def test_artifact_cross_codec_bitwise(shape):
 
 
 def test_artifact_cross_codec_f64():
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     f = synthetic_field("nyx", shape=(6, 7, 8), seed=5).astype(np.float64)
     xi = 1e-6 * float(np.ptp(f))
     with enable_x64():

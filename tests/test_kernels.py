@@ -127,3 +127,110 @@ def test_kernel_fix_loop_end_to_end():
         g = g2
     res = derive_edits(f, fh, xi, mode="fused", backend="reference")
     np.testing.assert_allclose(np.asarray(g), res.g, rtol=0, atol=0)
+
+
+@pytest.mark.parametrize("chunk", [1, 3, 4])
+@pytest.mark.parametrize("ascending", [True, False])
+def test_steepest_dirs_chunked_matches_whole_field(chunk, ascending):
+    """Z-chunked direction codes (the large-field path of
+    grid.steepest_dirs) equal the whole-field stencil bit for bit, ties
+    and domain edges included."""
+    from repro.core import grid
+    rng = np.random.default_rng(chunk)
+    f = jnp.asarray(rng.integers(0, 4, size=(10, 6, 7)).astype(np.float32))
+    lin = grid.linear_index(f.shape)
+    want = grid._codes(f, lin, ascending=ascending)
+    got = grid._codes_chunked(f, lin, chunk, ascending=ascending)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# 2D fields taller than one row block (2048 rows up to 128 columns,
+# 1024 up to 256): block seams, the 8-row halo blocks, and a partial
+# last block
+SHAPES_2D_BLOCKED = [(2100, 9), (2059, 130)]
+
+
+@pytest.mark.parametrize("shape", SHAPES_2D_BLOCKED)
+def test_extrema_kernel_row_blocks_match_ref(shape):
+    f, g, Mf, mf, maxf, minf, _, dnf = _setup(shape, 5)
+    got = extrema_masks_pallas(g, Mf, mf, maxf.astype(jnp.int32),
+                               minf.astype(jnp.int32), interpret=True)
+    want = kref.extrema_masks_ref(g, Mf, mf, maxf, minf)
+    for a, b, name in zip(got, want,
+                          ["up_c", "dn_c", "self", "demote", "promote"]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"mismatch in {name}")
+
+
+@pytest.mark.parametrize("shape", SHAPES_2D_BLOCKED)
+def test_fixpass_kernel_row_blocks_match_ref(shape):
+    f, g, Mf, mf, maxf, minf, upf, dnf = _setup(shape, 6)
+    lower = f - 0.3
+    up_c, dn_c, selfe, dem, pro = kref.extrema_masks_ref(g, Mf, mf, maxf, minf)
+    g2k, violk, tgtk = fix_pass_pallas(g, lower, selfe, dem, pro, up_c, dnf,
+                                       interpret=True)
+    g2r, violr = kref.fix_pass_ref(g, lower, selfe, dem, pro, up_c, dnf)
+    np.testing.assert_array_equal(np.asarray(g2k), np.asarray(g2r))
+    # per-row counts: sources are the masks' row sums, targets the rows
+    # the pass edited
+    np.testing.assert_array_equal(
+        np.asarray(violk),
+        np.asarray(selfe + dem + pro).sum(axis=1))
+    assert int(jnp.sum(violk)) == int(violr)
+    edited = np.asarray(g2k) != np.asarray(g)
+    np.testing.assert_array_equal(np.asarray(tgtk) > 0, edited.any(axis=1))
+
+
+@pytest.mark.parametrize("shape", SHAPES_2D_BLOCKED + SHAPES_2D)
+@pytest.mark.parametrize("step", [0.01, 0.2])
+def test_lorenzo_kernel_2d_matches_ref(shape, step):
+    rng = np.random.default_rng(8)
+    f = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    got = lorenzo_quant_pallas(f, step, interpret=True)
+    want = kref.lorenzo_quant_ref(f, step)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_tiled_2d_row_placement_matches_full():
+    """A 2D row tile placed with slab_lo/n_slabs_total, cut into row
+    blocks, reproduces the full field on rows whose halo is inside."""
+    shape = (2100, 13)
+    f, g, Mf, mf, maxf, minf, _, dnf = _setup(shape, 9)
+    full = extrema_masks_pallas(g, Mf, mf, maxf.astype(jnp.int32),
+                                minf.astype(jnp.int32), interpret=True)
+    a, b = 3, 2097
+    tile = extrema_masks_pallas(
+        g[a:b], Mf[a:b], mf[a:b],
+        maxf[a:b].astype(jnp.int32), minf[a:b].astype(jnp.int32),
+        interpret=True, slab_lo=a, n_slabs_total=shape[0])
+    for got, want, name in zip(tile, full,
+                               ["up_c", "dn_c", "self", "demote", "promote"]):
+        np.testing.assert_array_equal(
+            np.asarray(got)[1:-1], np.asarray(want)[a + 1:b - 1],
+            err_msg=f"tiled mismatch in {name}")
+
+
+def test_vmem_request_covers_blocks_and_refuses_oversized_planes():
+    """Every stencil kernel states a scoped-VMEM limit that holds its
+    double-buffered blocks and temporaries; a plane past the chip's
+    VMEM raises instead of switching backends."""
+    from repro.kernels import extrema as kx
+    lay = kx.slab_layout((4, 512, 512))
+    nbytes = lay.block_bytes(4, halo=3, center=7)
+    temps = kx.TEMP_PLANES["extrema", 3] * lay.plane_bytes(4)
+    limit = kx.vmem_params(nbytes, temps, "extrema").vmem_limit_bytes
+    assert 2 * nbytes + temps <= limit <= kx.VMEM_REQUEST_CAP
+    big = kx.slab_layout((4, 2048, 2048))
+    with pytest.raises(ValueError, match="too large"):
+        kx.vmem_params(big.block_bytes(4, halo=3, center=7),
+                       kx.TEMP_PLANES["extrema", 3] * big.plane_bytes(4),
+                       "extrema")
+    # the lowered kernel refuses the oversized plane before Mosaic sees it
+    g = jax.ShapeDtypeStruct((2, 2048, 2048), jnp.float32)
+    i = jax.ShapeDtypeStruct((2, 2048, 2048), jnp.int32)
+    with pytest.raises(ValueError, match="too large for the slab kernels"):
+        jax.eval_shape(lambda g, M, m, a, b: extrema_masks_pallas(
+            g, M, m, a, b, interpret=False), g, i, i, i, i)
+    # 2D fields never hit the limit: rows per block shrink with width
+    assert kx.slab_layout((1800, 3600)).P % 8 == 0
+    assert kx.slab_layout((5, 7)).P == 5

@@ -108,7 +108,7 @@ def test_conformance_f32(codec_name, shape, spec):
 @pytest.mark.parametrize("shape", SHAPES, ids=["2d", "3d"])
 @pytest.mark.parametrize("codec_name", CODECS)
 def test_conformance_f64_under_x64(codec_name, shape, spec):
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     backend, mesh = _backend_mesh(spec)
     f = _field(shape, np.float64, seed=7 + len(shape))
     xi = 0.03
@@ -288,7 +288,7 @@ def test_zfplike_f64_roundtrip_keeps_dtype_and_tight_bound():
     f32, losing the precision the bound was derived in) and honor bounds
     near the codec's block-floating-point floor (~amax * 2^-25 per
     fractional bit budget; bounds below it surface at derive time)."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     f = _field((6, 7), np.float64, seed=10)
     xi = 3e-7
     fh = zfplike.zfp_decompress(zfplike.zfp_compress(f, xi))
