@@ -14,6 +14,7 @@ import zlib
 from typing import Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 _MAGIC = b"MSE1"
 
@@ -123,38 +124,42 @@ def encode_edits(idx: np.ndarray, val: np.ndarray, value_dtype="f4") -> bytes:
     caller's edit extraction is broken, and the delta coding + the
     decompression scatter would otherwise mask it (re-sorting used to
     swallow duplicates silently; ``apply_edits`` would then drop or
-    double-apply them depending on the path)."""
+    double-apply them depending on the path).
+
+    The profiler span ``msz.encode.edits`` covers the coding."""
     if value_dtype not in ("f4", "f8", "bf16"):
         raise ValueError(
             f"unknown edit value_dtype {value_dtype!r}; expected "
             "'f4', 'f8', or 'bf16'")
-    idx = np.asarray(idx, np.int64)
-    val = np.asarray(val, np.float64 if value_dtype == "f8" else np.float32)
-    if idx.size != val.size:
-        raise ValueError("idx/val length mismatch")
-    if idx.size and np.any(np.diff(idx) <= 0):
-        order = np.argsort(idx, kind="stable")
-        idx, val = idx[order], val[order]
-        if np.any(np.diff(idx) == 0):
-            dup = int(idx[np.flatnonzero(np.diff(idx) == 0)[0]])
-            raise ValueError(
-                f"duplicate edit index {dup}: edits must target each vertex "
-                "at most once (broken upstream edit extraction?)")
-    deltas = np.diff(idx, prepend=np.int64(0))
-    key_stream = zlib.compress(_varint_encode(deltas), 9)
-    if value_dtype == "bf16":
-        vb = _f32_to_bf16(val)
-        val_stream = zlib.compress(vb.tobytes(), 9)
-        dt = 1
-    elif value_dtype == "f8":
-        val_stream = zlib.compress(val.tobytes(), 9)
-        dt = 2
-    else:
-        val_stream = zlib.compress(val.tobytes(), 9)
-        dt = 0
-    hdr = struct.pack("<4sBQQQ", _MAGIC, dt, idx.size,
-                      len(key_stream), len(val_stream))
-    return hdr + key_stream + val_stream
+    with TraceAnnotation("msz.encode.edits"):
+        idx = np.asarray(idx, np.int64)
+        val = np.asarray(val,
+                         np.float64 if value_dtype == "f8" else np.float32)
+        if idx.size != val.size:
+            raise ValueError("idx/val length mismatch")
+        if idx.size and np.any(np.diff(idx) <= 0):
+            order = np.argsort(idx, kind="stable")
+            idx, val = idx[order], val[order]
+            if np.any(np.diff(idx) == 0):
+                dup = int(idx[np.flatnonzero(np.diff(idx) == 0)[0]])
+                raise ValueError(
+                    f"duplicate edit index {dup}: edits must target each "
+                    "vertex at most once (broken upstream edit extraction?)")
+        deltas = np.diff(idx, prepend=np.int64(0))
+        key_stream = zlib.compress(_varint_encode(deltas), 9)
+        if value_dtype == "bf16":
+            vb = _f32_to_bf16(val)
+            val_stream = zlib.compress(vb.tobytes(), 9)
+            dt = 1
+        elif value_dtype == "f8":
+            val_stream = zlib.compress(val.tobytes(), 9)
+            dt = 2
+        else:
+            val_stream = zlib.compress(val.tobytes(), 9)
+            dt = 0
+        hdr = struct.pack("<4sBQQQ", _MAGIC, dt, idx.size,
+                          len(key_stream), len(val_stream))
+        return hdr + key_stream + val_stream
 
 
 def decode_edits(blob: bytes) -> Tuple[np.ndarray, np.ndarray]:

@@ -49,6 +49,7 @@ from typing import Callable, List, Literal, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core import fixes
 from ..core.backend import BackendLike, resolve_backend
@@ -80,15 +81,22 @@ def _h2d(x: np.ndarray) -> jnp.ndarray:
     and fails untracked ones."""
     if _transfer_hook is not None:
         _transfer_hook("h2d", x.nbytes)
-    return jax.device_put(x)   # mszlint: disable=transfer-discipline — the choke point itself
+    with TraceAnnotation("msz.h2d", nbytes=x.nbytes):
+        return jax.device_put(x)   # mszlint: disable=transfer-discipline — the choke point itself
 
 
 def _d2h(x: jnp.ndarray) -> np.ndarray:
     """The audited device->host seam (explicit ``jax.device_get``);
-    twin of ``_h2d``."""
+    twin of ``_h2d``. Two profiler spans: ``msz.wait`` while the host
+    blocks on the device computing ``x``, then ``msz.d2h`` for the
+    transfer alone. ``device_get`` would block anyway, so the split adds
+    no synchronisation."""
     if _transfer_hook is not None:
         _transfer_hook("d2h", x.nbytes)
-    return jax.device_get(x)   # mszlint: disable=transfer-discipline — the choke point itself
+    with TraceAnnotation("msz.wait"):
+        jax.block_until_ready(x)
+    with TraceAnnotation("msz.d2h", nbytes=x.nbytes):
+        return jax.device_get(x)   # mszlint: disable=transfer-discipline — the choke point itself
 
 
 # ---------------------------------------------------------------------------
@@ -107,7 +115,8 @@ def _device_path_reason(f: np.ndarray, xi: float, base: str, mode: str
                         ) -> Tuple[Optional[str], Optional[float]]:
     """(None, step) when the device path can serve this call, else
     (why not, None). One field scan total: max|f| feeds both the step
-    headroom and the range-precondition check."""
+    headroom and the range-precondition check; the profiler span
+    ``msz.precheck`` covers that host scan."""
     if base != "szlike":
         return (f"device path serves the szlike base only (got {base!r}); "
                 "zfplike's block transform stays host-side"), None
@@ -119,12 +128,13 @@ def _device_path_reason(f: np.ndarray, xi: float, base: str, mode: str
     if not _device_dtype_ok(f.dtype):
         return (f"device path needs float32 (or float64 under jax x64 "
                 f"mode); got {f.dtype}"), None
-    amax = float(np.max(np.abs(f)))
-    step = szlike.effective_step(f, xi, amax=amax)
-    try:
-        szlike.check_int32_range(f, step / 2.0, amax=amax)
-    except ValueError as e:
-        return str(e), None
+    with TraceAnnotation("msz.precheck"):
+        amax = float(np.max(np.abs(f)))
+        step = szlike.effective_step(f, xi, amax=amax)
+        try:
+            szlike.check_int32_range(f, step / 2.0, amax=amax)
+        except ValueError as e:
+            return str(e), None
     return None, step
 
 

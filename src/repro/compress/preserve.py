@@ -64,7 +64,12 @@ ARTIFACT_VERSION = 4
 @dataclasses.dataclass
 class CompressedArtifact:
     """One MSS-preserving compression result: the base codec's payload
-    plus the MSz edit blob, with the metadata both read paths need."""
+    plus the MSz edit blob, with the metadata both read paths need.
+
+    On the device path ``t_fix`` ends right after the ``extract_edits``
+    dispatch, which it does not wait for: the wait for edit compaction
+    lands in ``t_base``, whose part after ``t_transform`` is the pull of
+    the codes and edits and the host's entropy and edit coding."""
     base: str
     base_payload: bytes
     edit_payload: bytes

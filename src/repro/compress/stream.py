@@ -50,6 +50,7 @@ from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Callable, Dict, Hashable, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core import fixes
 from ..core.backend import BackendLike, resolve_backend
@@ -250,6 +251,8 @@ class _StreamBase:
         self._nbytes_d2h = 0                 # guarded-by: self._lock
         self._t_device = 0.0                 # guarded-by: self._lock
         self._t_encode = 0.0                 # guarded-by: self._lock
+        self._dispatched = 0                 # guarded-by: self._lock
+        self._queue_wait = 0.0               # guarded-by: self._lock
         # guarded-by: self._lock
         self._t_first_submit: Optional[float] = None
         # guarded-by: self._lock
@@ -397,21 +400,28 @@ class _StreamBase:
     def _take_batch(self) -> Optional[List[_Request]]:
         """Pop the next coalesced same-spec batch (up to ``max_batch``
         members), lingering ``linger_ms`` for stragglers when the queue
-        drains below a full batch. None = closed and fully drained."""
+        drains below a full batch. None = closed and fully drained.
+        Profiler spans: ``msz.stream.empty`` while no request is pending,
+        ``msz.stream.linger`` while a sub-full batch waits."""
         with self._lock:
-            while not self._pending and not self._closed:
-                self._wake.wait()
+            if not self._pending and not self._closed:
+                with TraceAnnotation("msz.stream.empty"):
+                    while not self._pending and not self._closed:
+                        self._wake.wait()
             if not self._pending:
                 return None
             spec = self._pending[0].spec
             batch = self._pop_spec_locked(spec)
             deadline = time.perf_counter() + self.linger_s * self._linger_scale
-            while (len(batch) < self.max_batch and not self._closed):
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0 or not self._wake.wait(timeout=remaining):
-                    break
-                batch.extend(self._pop_spec_locked(
-                    spec, self.max_batch - len(batch)))
+            if len(batch) < self.max_batch and not self._closed:
+                with TraceAnnotation("msz.stream.linger"):
+                    while len(batch) < self.max_batch and not self._closed:
+                        remaining = deadline - time.perf_counter()
+                        if remaining <= 0 or \
+                                not self._wake.wait(timeout=remaining):
+                            break
+                        batch.extend(self._pop_spec_locked(
+                            spec, self.max_batch - len(batch)))
             return batch
 
     def _pop_spec_locked(self, spec: Tuple,  # guarded-by: self._lock
@@ -425,6 +435,9 @@ class _StreamBase:
             else:
                 rest.append(req)
         self._pending = collections.deque(rest)
+        now = time.perf_counter()
+        self._dispatched += len(taken)
+        self._queue_wait += sum(now - req.t_submit for req in taken)
         return taken
 
     # -- stats --------------------------------------------------------
@@ -486,14 +499,18 @@ class _StreamBase:
         """Live counter snapshot — the service stats endpoint surfaces
         this dict as JSON. ``fields_per_sec`` covers first submit to last
         completion; ``batch_occupancy`` is real members / dispatched
-        member slots (padding included in the denominator)."""
+        member slots (padding included in the denominator);
+        ``queue_wait_s`` sums, over the ``dispatched`` requests the
+        scheduler has popped, the time from submit to pop. ``t_device_s``
+        is host seconds around each batch's device stage (what the
+        straggler watchdog observes), not device time."""
         with self._lock:
             elapsed = None
             if self._t_first_submit is not None:
                 end = self._t_last_done if self._in_flight == 0 and \
                     self._t_last_done else time.perf_counter()
                 elapsed = max(end - self._t_first_submit, 1e-9)
-            dispatched = self._members_real + self._members_padded
+            slots = self._members_real + self._members_padded
             return dict(
                 submitted=self._submitted,
                 completed=self._completed,
@@ -505,13 +522,15 @@ class _StreamBase:
                 max_batch=self.max_batch,
                 mean_batch=(self._members_real / self._batches
                             if self._batches else 0.0),
-                batch_occupancy=(self._members_real / dispatched
-                                 if dispatched else 0.0),
+                batch_occupancy=(self._members_real / slots
+                                 if slots else 0.0),
                 padded_members=self._members_padded,
                 nbytes_h2d=self._nbytes_h2d,
                 nbytes_d2h=self._nbytes_d2h,
                 t_device_s=self._t_device,
                 t_encode_s=self._t_encode,
+                dispatched=self._dispatched,
+                queue_wait_s=self._queue_wait,
                 fields_per_sec=(self._completed / elapsed
                                 if elapsed and self._completed else 0.0),
                 fix_modes=dict(self._fix_mode_counts),

@@ -47,6 +47,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # SZJ2: the dequantization arithmetic runs in the field's dtype (the
 # shared host/device contract above). SZJ1 blobs used f64-multiply-then-
@@ -270,14 +271,16 @@ def sz_encode_residuals(r: np.ndarray, shape: Tuple[int, ...],
     bytes. ``entropy`` picks the residual codec (``ENTROPIES``);
     "device-pack" runs the chunked-bitplane packer's numpy mirror here
     (the device pipeline hands its already-packed stream to
-    ``sz_encode_packed`` directly and skips this)."""
+    ``sz_encode_packed`` directly and skips this). The profiler span
+    ``msz.encode.residuals`` covers the coding."""
     check_entropy(entropy)
-    if entropy == "device-pack":
-        from ..kernels import pack
-        words, bits = pack.pack_codes_host(np.asarray(r))
-        return sz_encode_packed(words, bits, shape, dtype, step)
-    return _szlike_header(_MAGIC, shape, dtype, step) \
-        + _pack_residuals(np.asarray(r))
+    with TraceAnnotation("msz.encode.residuals"):
+        if entropy == "device-pack":
+            from ..kernels import pack
+            words, bits = pack.pack_codes_host(np.asarray(r))
+            return sz_encode_packed(words, bits, shape, dtype, step)
+        return _szlike_header(_MAGIC, shape, dtype, step) \
+            + _pack_residuals(np.asarray(r))
 
 
 def sz_encode_packed(words: np.ndarray, bits: np.ndarray,
