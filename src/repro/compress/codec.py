@@ -6,17 +6,107 @@ ascending and delta-encoded (the paper's observation: edits form
 RLE/varint-friendly), varint-packed, then DEFLATE'd. Values are stored as
 f32, f64 (the exact dtype for f64 fields), or bf16 (the bound-tight
 beyond-paper mode) and DEFLATE'd separately. DEFLATE = LZ77 + Huffman, i.e. the paper's Huffman+GZIP stage.
+
+Each stream is one standard zlib stream at level 9. A stream longer than
+``CHUNK`` bytes (1 MiB) is cut into ``CHUNK``-sized pieces that are
+DEFLATE'd in parallel on one shared thread pool (zlib releases the GIL),
+each piece primed with the 32 KiB of input before it as its dictionary
+and ended by a sync flush, the last by a final flush; the pieces are
+joined under the zlib header and the whole input's Adler-32 (the pigz
+scheme), so stock ``zlib.decompress`` reads the result. A piece's bytes
+depend only on its own input and the 32 KiB before it, never on how many
+threads ran or in what order they finished, so the output is the same
+on every host. A stream of at most ``CHUNK`` bytes is one
+``zlib.compress(buf, 9)``, byte for byte.
 """
 from __future__ import annotations
 
+import os
 import struct
+import threading
 import zlib
-from typing import Optional, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 from jax.profiler import TraceAnnotation
 
 _MAGIC = b"MSE1"
+
+#: raw bytes per DEFLATE chunk of a stream longer than one chunk
+CHUNK = 1 << 20
+#: DEFLATE's window: the input before a chunk that primes it
+_WINDOW = 1 << 15
+#: zlib header of a level-9, 32 KiB-window stream without a dictionary
+_ZLIB_HEAD = b"\x78\xda"
+
+_lock = threading.Lock()
+_pool: Optional[ThreadPoolExecutor] = None  # guarded-by: _lock
+blobs_coded = 0     # guarded-by: _lock
+chunked_streams = 0  # guarded-by: _lock
+chunks_coded = 0    # guarded-by: _lock
+
+
+def coding_counts() -> Dict[str, int]:
+    """Edit blobs coded in this process, the streams of them that took
+    the chunked writer, and the chunks those streams were cut into."""
+    with _lock:
+        return {"blobs": blobs_coded, "chunked_streams": chunked_streams,
+                "chunks": chunks_coded}
+
+
+def _shared_pool() -> ThreadPoolExecutor:
+    """The one pool every caller's chunks run on, created on first use
+    and sized to what the host runs at once."""
+    global _pool
+    with _lock:
+        if _pool is None:
+            _pool = ThreadPoolExecutor(max_workers=os.cpu_count() or 1,
+                                       thread_name_prefix="msz-deflate")
+        return _pool
+
+
+def _deflate_chunk(buf: memoryview, lo: int, hi: int) -> bytes:
+    """Raw level-9 DEFLATE of ``buf[lo:hi]``, primed with the window
+    before ``lo``; sync-flushed unless it is the stream's last chunk.
+    The match search is ``zlib.compress(buf, 9)``'s own (its default
+    memory level), so a chunked stream costs the bits a single one does."""
+    args = (9, zlib.DEFLATED, -15, zlib.DEF_MEM_LEVEL)
+    if lo:
+        c = zlib.compressobj(*args, zdict=buf[max(0, lo - _WINDOW):lo])
+    else:
+        c = zlib.compressobj(*args)
+    last = hi == len(buf)
+    return c.compress(buf[lo:hi]) + c.flush(
+        zlib.Z_FINISH if last else zlib.Z_SYNC_FLUSH)
+
+
+def _zlib9(bufs: List[bytes]) -> List[bytes]:
+    """One level-9 zlib stream per buffer, chunks of every buffer in
+    flight on the shared pool at once."""
+    global chunked_streams, chunks_coded
+    jobs = []
+    for b in bufs:
+        if len(b) <= CHUNK:
+            jobs.append(None)
+            continue
+        mv = memoryview(b)
+        pool = _shared_pool()
+        jobs.append([pool.submit(_deflate_chunk, mv, lo,
+                                 min(lo + CHUNK, len(b)))
+                     for lo in range(0, len(b), CHUNK)])
+        with _lock:
+            chunked_streams += 1
+            chunks_coded += len(jobs[-1])
+    out = []
+    for b, futs in zip(bufs, jobs):
+        if futs is None:
+            out.append(zlib.compress(b, 9))
+            continue
+        tail = struct.pack(">I", zlib.adler32(b))
+        out.append(b"".join([_ZLIB_HEAD] + [f.result() for f in futs]
+                            + [tail]))
+    return out
 
 
 def _varint_encode(a: np.ndarray) -> bytes:
@@ -126,7 +216,12 @@ def encode_edits(idx: np.ndarray, val: np.ndarray, value_dtype="f4") -> bytes:
     swallow duplicates silently; ``apply_edits`` would then drop or
     double-apply them depending on the path).
 
-    The profiler span ``msz.encode.edits`` covers the coding."""
+    Keys and values are each one level-9 zlib stream; a stream longer
+    than ``CHUNK`` (1 MiB) is DEFLATE'd as parallel chunks on the shared
+    pool and comes out the same whatever the host's core count (module
+    docstring). The profiler span ``msz.encode.edits`` covers the
+    coding; the pool's threads open no span of their own."""
+    global blobs_coded
     if value_dtype not in ("f4", "f8", "bf16"):
         raise ValueError(
             f"unknown edit value_dtype {value_dtype!r}; expected "
@@ -146,17 +241,13 @@ def encode_edits(idx: np.ndarray, val: np.ndarray, value_dtype="f4") -> bytes:
                     f"duplicate edit index {dup}: edits must target each "
                     "vertex at most once (broken upstream edit extraction?)")
         deltas = np.diff(idx, prepend=np.int64(0))
-        key_stream = zlib.compress(_varint_encode(deltas), 9)
         if value_dtype == "bf16":
-            vb = _f32_to_bf16(val)
-            val_stream = zlib.compress(vb.tobytes(), 9)
-            dt = 1
-        elif value_dtype == "f8":
-            val_stream = zlib.compress(val.tobytes(), 9)
-            dt = 2
+            raw, dt = _f32_to_bf16(val).tobytes(), 1
         else:
-            val_stream = zlib.compress(val.tobytes(), 9)
-            dt = 0
+            raw, dt = val.tobytes(), 2 if value_dtype == "f8" else 0
+        key_stream, val_stream = _zlib9([_varint_encode(deltas), raw])
+        with _lock:
+            blobs_coded += 1
         hdr = struct.pack("<4sBQQQ", _MAGIC, dt, idx.size,
                           len(key_stream), len(val_stream))
         return hdr + key_stream + val_stream

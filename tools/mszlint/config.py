@@ -14,7 +14,8 @@ benchmarks`` is a meaningful statement, not a fought-down noise floor.
 * ``scatter-discipline`` runs repo-wide over src, tests, and benchmarks:
   a duplicate-dropping ``+=`` is wrong anywhere.
 * ``lock-guard`` covers the threaded modules: the stream scheduler, the
-  serve-side compression manager, and calibration's process-wide caches.
+  serve-side compression manager, calibration's process-wide caches, and
+  the edit codec's shared DEFLATE pool and counters.
 * ``int32-range`` / ``interpret-policy`` cover all of ``src/repro``.
 """
 from __future__ import annotations
@@ -79,6 +80,7 @@ DEFAULT = Config(
         "lock-guard": (
             "*/compress/stream.py",
             "*/compress/calibrate.py",
+            "*/compress/codec.py",
             "*/serve/compression.py",
         ),
         "int32-range": (
