@@ -1,11 +1,14 @@
 """Fields for the benchmark, made on the device from the run's seed.
 
-Copies of the program's ``nyx_like`` and ``climate_like`` spectral
-recipes (``repro.data.fields``), written in ``jax.random`` and
-``jnp.fft`` so that a 512^3 pool is one jitted call on the chip instead
-of float64 meshgrids and complex128 FFTs on the host. The content is not
-the program's bit for bit (float32 FFTs, another generator); it has the
-same spectrum and shape of values, which is what the recipes promise.
+A recipe is a file of its own, ``bench/recipes/<name>.py``, found by
+name (``bench/lookup.py``): ``make(key, shape)`` returns one float32
+field. The ``nyx_like`` and ``climate_like`` recipes copy the program's
+(``repro.data.fields``) in ``jax.random`` and ``jnp.fft``, so that a
+512^3 pool is one jitted call on the chip instead of float64 meshgrids
+and complex128 FFTs on the host. The content is not the program's bit
+for bit (float32 FFTs, another generator); it has the same spectrum and
+shape of values, which is what the recipes promise. This module keeps
+the spectral helpers that recipes share.
 
 A seed of any size maps to a key through ``numpy.random.SeedSequence``,
 so seeds beyond 32 bits are distinct keys too. A configuration with a
@@ -15,11 +18,14 @@ shift it (``field_pool``).
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, Optional, Sequence, Tuple
+from pathlib import Path
+from typing import Callable, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import lookup
 
 
 def key_for(seed: int, stream: int = 0) -> jax.Array:
@@ -49,50 +55,36 @@ def _spectral(key: jax.Array, shape: Tuple[int, ...], slope: float
     return (f - f.mean()) / (f.std() + 1e-12)
 
 
-def nyx_like(key: jax.Array, shape: Tuple[int, ...]) -> jnp.ndarray:
-    """Cosmology-like log-normal density (``repro.data.fields.nyx_like``)."""
-    return jnp.exp(1.2 * _spectral(key, shape, -2.5))
-
-
-def climate_like(key: jax.Array, shape: Tuple[int, ...]) -> jnp.ndarray:
-    """Smooth bands with embedded filaments
-    (``repro.data.fields.climate_like``)."""
-    g = _spectral(key, shape, -3.0)
-    bands = jnp.sin(jnp.linspace(0.0, 4.0 * jnp.pi, shape[0],
-                                 dtype=jnp.float32))
-    return g + 0.8 * bands.reshape((shape[0],) + (1,) * (len(shape) - 1))
-
-
-RECIPES: Dict[str, Callable[[jax.Array, Tuple[int, ...]], jnp.ndarray]] = {
-    "nyx_like": nyx_like,
-    "climate_like": climate_like,
-}
-
-
-@functools.partial(jax.jit, static_argnames=("recipe", "shape", "n"))
-def _pool(key: jax.Array, shifts: jax.Array, recipe: str,
+@functools.partial(jax.jit, static_argnames=("makers", "shape", "n"))
+def _pool(key: jax.Array, shifts: jax.Array, makers: Tuple[Callable, ...],
           shape: Tuple[int, ...], n: int) -> jnp.ndarray:
     keys = jax.random.split(key, n)
     axes = tuple(range(len(shape)))
+    branches = [lambda k, make=make: make(k, shape) for make in makers]
+    which = jnp.arange(n, dtype=jnp.int32) % len(makers)
     return jax.lax.map(
-        lambda ks: jnp.roll(RECIPES[recipe](ks[0], shape), ks[1], axes),
-        (keys, shifts))
+        lambda ks: jnp.roll(jax.lax.switch(ks[2], branches, ks[0]), ks[1],
+                            axes),
+        (keys, shifts, which))
 
 
-def field_pool(recipe: str, shape: Sequence[int], n: int, seed: int,
-               content_seed: Optional[int] = None) -> list:
-    """``n`` float32 fields of ``shape`` by ``recipe``, made in one jitted
-    call on the default device and returned as host arrays, since the
-    program takes host arrays.
+def field_pool(recipe: Union[str, Sequence[str]], shape: Sequence[int],
+               n: int, seed: int, content_seed: Optional[int] = None,
+               base: Path = lookup.BENCH) -> list:
+    """``n`` float32 fields of ``shape``, made in one jitted call on the
+    default device and returned as host arrays, since the program takes
+    host arrays. ``recipe`` names a file under ``base/recipes``, or is a
+    list of such names, of which entry ``i % len`` makes member ``i``.
 
     Without ``content_seed`` the run's ``seed`` draws the fields. With it,
     ``content_seed`` draws them, the same for every run, and ``seed``
     draws a circular shift of each along every axis: a spectral field is
     periodic, so every seed then compresses the same values, with the
     same range and so the same ``xi``, in another arrangement."""
-    if recipe not in RECIPES:
-        raise KeyError(f"unknown field recipe {recipe!r}; known: "
-                       f"{sorted(RECIPES)}")
+    names = [recipe] if isinstance(recipe, str) else list(recipe)
+    if not names:
+        raise ValueError("a configuration names at least one recipe")
+    makers = tuple(lookup.find("recipes", r, base=base).make for r in names)
     shape = tuple(int(s) for s in shape)
     shifts = np.zeros((int(n), len(shape)), np.int32)
     if content_seed is not None:
@@ -100,5 +92,5 @@ def field_pool(recipe: str, shape: Sequence[int], n: int, seed: int,
         shifts = rng.integers(0, shape, size=shifts.shape).astype(np.int32)
         seed = content_seed
     pool = np.asarray(jax.device_get(
-        _pool(key_for(seed, 1), jnp.asarray(shifts), recipe, shape, int(n))))
+        _pool(key_for(seed, 1), jnp.asarray(shifts), makers, shape, int(n))))
     return [pool[i] for i in range(n)]

@@ -1,13 +1,18 @@
 """One run of one cell: set-up, the measured window, metrics, the check.
 
-Everything that belongs to one configuration, traffic mix or metric is
-found by name: ``BENCHMARK.json`` names the cell's configuration file
-and traffic mix (``bench/traffic/<mix>.json``), and each metric is read
-by ``bench/metrics/<name>.py``, or, for a name with a dot such as
+Everything that belongs to one configuration, traffic mix, recipe,
+loop, entry or metric is found by name, as a file of its own
+(``bench/lookup.py``): ``BENCHMARK.json`` names the cell's configuration
+file and traffic mix (``bench/traffic/<mix>.json``); the configuration
+names its field recipe or recipes (``bench/recipes/<name>.py``); the
+traffic names its loop (``bench/loops/<loop>.py``) and its entry into
+the program (``bench/entries/<entry>.py``); and each metric is read by
+``bench/metrics/<name>.py``, or, for a name with a dot such as
 ``device_idle_pct.write``, by ``bench/metrics/<part before the dot>.py``
 when no file of the full name exists. A reader is a module with
 ``read(run) -> float | None``; None means it found nothing to read, and
-the metric is left out of the result line.
+the metric is left out of the result line. A new cell adds such files;
+it edits none.
 
 ``run_cell`` is the whole run; ``bench/run.py`` is its command line.
 Tests call ``run_cell`` with ``require_tpu=False`` and ``overrides`` to
@@ -18,7 +23,6 @@ from __future__ import annotations
 import copy
 import dataclasses
 import gc
-import importlib.util
 import json
 import math
 import sys
@@ -28,6 +32,8 @@ from pathlib import Path
 from typing import Any, Callable, Dict, Optional
 
 import numpy as np
+
+from bench import lookup
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -99,16 +105,8 @@ def metrics_for(bench: Dict[str, Any], workload: str, trace: bool) -> list:
 def reader(name: str, root: Path = ROOT) -> Callable:
     """``read`` of the metric's reader module (full name first, then the
     part before the first dot)."""
-    base = root / "bench" / "metrics"
-    for stem in (name, name.split(".")[0]):
-        path = base / f"{stem}.py"
-        if path.exists():
-            spec = importlib.util.spec_from_file_location(
-                f"bench_metric_{stem.replace('.', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
-    raise FileNotFoundError(f"no reader for metric {name!r} under {base}")
+    return lookup.find("metrics", name, name.split(".")[0],
+                       base=root / "bench").read
 
 
 def _merge(base: Dict[str, Any], over: Optional[Dict[str, Any]]):
@@ -197,33 +195,12 @@ def _program():
 # set-up
 # ---------------------------------------------------------------------------
 
-def _warm_service(entry, op: str, items: list, counter: CompileCounter,
-                  rounds: int = 4) -> int:
-    """Send every field through the service once, in bursts of 4, then
-    bursts of 4, 2 and 1 of the first fields until a round compiles
-    nothing, so that each padded batch size and each field's shapes have
-    compiled. Returns the rounds taken."""
-    def burst(chunk):
-        for fut in [entry.submit(op, it) for it in chunk]:
-            fut.result()
-
-    for s in range(0, len(items), 4):
-        burst(items[s:s + 4])
-    for r in range(rounds):
-        before = counter.n
-        for size in (4, 2, 1):
-            burst(items[:size])
-        if counter.n == before:
-            return r + 1
-    return rounds
-
-
 def set_up(traffic: Dict[str, Any], program, fields: list, xis: list,
-           counter: CompileCounter):
-    """(entry, items) ready for the window: every shape the window will
-    use has run once."""
-    from bench.load import ENTRIES
-    entry = ENTRIES[traffic["entry"]](program)
+           counter: CompileCounter, root: Path = ROOT):
+    """(entry, items) ready for the window: the entry has warmed every
+    shape the window will use."""
+    entry = lookup.find("entries", traffic["entry"],
+                        base=root / "bench").start(program)
     op = traffic["op"]
     comp = [(f, xi) for f, xi in zip(fields, xis)]
     if op == "compress":
@@ -231,11 +208,7 @@ def set_up(traffic: Dict[str, Any], program, fields: list, xis: list,
     else:                    # the artifacts the readers read
         items = [program.compress_preserving_mss(f, xi) for f, xi in comp]
     counter.armed = True
-    if traffic["entry"] == "service":
-        _warm_service(entry, op, items, counter)
-    else:
-        for it in items:
-            entry.call(op, it)
+    entry.warm(op, items, traffic, counter)
     counter.armed = False
     return entry, items
 
@@ -346,15 +319,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         # every program goes to the persistent cache, however fast it
         # compiled, so that a second run of the cell compiles nothing
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    from bench import arith, fields as fieldgen, load
+    from bench import arith, fields as fieldgen
 
     counter = CompileCounter()
     rng = np.random.default_rng(np.random.SeedSequence([int(seed), 2]))
     fields = fieldgen.field_pool(config["recipe"], config["shape"],
                                  int(traffic["pool"]), seed,
-                                 config.get("content_seed"))
+                                 config.get("content_seed"),
+                                 base=root / "bench")
     xis = [float(config["xi_rel"]) * float(np.ptp(f)) for f in fields]
-    entry, items = set_up(traffic, program, fields, xis, counter)
+    loop = lookup.find("loops", traffic["loop"], base=root / "bench")
+    entry, items = set_up(traffic, program, fields, xis, counter, root)
     log(f"# warm-up programs {counter.summary()}")
     stats_before = entry.stats() if hasattr(entry, "stats") else None
 
@@ -372,19 +347,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     window_start = time.perf_counter()
     setup_s = window_start - t_start
     with span("bench.window"):
-        if traffic["loop"] == "open":
-            reqs = load.open_loop(entry, traffic["op"], items, seconds, rng,
-                                  traffic, sample, span)
-        else:
-            reqs = load.closed_loop(entry, traffic["op"], items, seconds,
-                                    rng, sample, span)
+        reqs = loop.run(entry, traffic["op"], items, seconds, rng, traffic,
+                        sample, span)
     counter.armed = False
     if trace:
         jax.profiler.stop_trace()
     stats_after = entry.stats() if hasattr(entry, "stats") else None
     entry.close()
     log(f"# compiles_in_window {counter.summary()}")
-    if reqs and traffic["loop"] == "open":
+    if reqs:
         lat = arith.due_latencies_ms([r.due for r in reqs],
                                      [r.done if r.ok else None for r in reqs])
         log("# latency_ms " + json.dumps(

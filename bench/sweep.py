@@ -33,7 +33,9 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(ROOT))
     import numpy as np
 
-    from bench import arith, fields, harness, load
+    import jax
+
+    from bench import arith, fields, harness, lookup
     cell, config, traffic = harness.cell_spec(harness.load_benchmark(),
                                               args.workload)
     harness.device_info(int(cell["chips"]), require_tpu=True)
@@ -46,11 +48,13 @@ def main(argv=None) -> int:
                              config.get("content_seed"))
     xis = [float(config["xi_rel"]) * float(np.ptp(f)) for f in pool]
     entry, items = harness.set_up(traffic, program, pool, xis, counter)
+    loop = lookup.find("loops", traffic["loop"])
     rng = np.random.default_rng(args.seed)
     for rate in args.rates:
         t = dict(traffic, rate_per_s=rate, drain_s=30)
         t0 = time.perf_counter()
-        reqs = load.open_loop(entry, t["op"], items, args.seconds, rng, t, 0)
+        reqs = loop.run(entry, t["op"], items, args.seconds, rng, t, 0,
+                        jax.profiler.TraceAnnotation)
         lat = arith.due_latencies_ms([r.due for r in reqs],
                                      [r.done if r.ok else None for r in reqs])
         done = [r.done for r in reqs if r.ok]

@@ -85,8 +85,11 @@ def test_traced_run_reports_what_it_can_read_on_cpu(tmp_path):
 
 
 def test_a_new_cell_runs_from_added_files_alone(tmp_path):
-    """A later PR adds a configuration, a traffic mix and a metric reader
-    as files, and entries in BENCHMARK.json; no existing file changes."""
+    """A later PR adds configurations, traffic mixes, a field recipe and
+    a metric reader as files, and entries in BENCHMARK.json; no existing
+    file changes. One added cell is a closed one-shot loop; the other
+    sends steps to the service, over a list of two recipes, one of them
+    added: mostly exact zeros, as a hydrometeor field is."""
     shutil.copytree(ROOT / "bench", tmp_path / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -101,11 +104,29 @@ def test_a_new_cell_runs_from_added_files_alone(tmp_path):
         "def read(run):\n"
         "    xs = [r.stats['edit_ratio'] for r in run.done]\n"
         "    return 100.0 * sum(xs) / len(xs) if xs else None\n")
-    bench["configs"].append({"name": "tiny2d", "source": "test",
-                             "file": "bench/configs/tiny2d.json",
-                             "reduced": [], "why": "test"})
+    (tmp_path / "bench/recipes/plateau.py").write_text(
+        "import jax.numpy as jnp\n"
+        "from bench.fields import _spectral\n\n\n"
+        "def make(key, shape):\n"
+        "    return jnp.maximum(_spectral(key, shape, -3.0) - 1.0, 0.0)\n")
+    (tmp_path / "bench/configs/tiny3d.json").write_text(json.dumps({
+        "name": "tiny3d", "shape": [6, 10, 12], "dtype": "float32",
+        "recipe": ["climate_like", "plateau"], "xi_rel": 0.002,
+        "guarantees": {"err_rel_tolerance": 1e-6}, "reduced": []}))
+    (tmp_path / "bench/traffic/stream.json").write_text(json.dumps({
+        "op": "compress", "entry": "service", "loop": "steps",
+        "pool": 3, "step": 3, "check_sample": 3}))
+    for name in ("tiny2d", "tiny3d"):
+        bench["configs"].append({"name": name, "source": "test",
+                                 "file": f"bench/configs/{name}.json",
+                                 "reduced": [], "why": "test"})
     bench["workloads"].append({"name": "tiny2d.pairs", "config": "tiny2d",
                                "traffic": "pairs", "chips": 1, "why": "t"})
+    bench["workloads"].append({"name": "tiny3d.stream", "config": "tiny3d",
+                               "traffic": "stream", "chips": 1, "why": "t"})
+    for m in bench["end_to_end"]:
+        if m["name"] in ("compress_MBps", "bits_per_value"):
+            m["workloads"].append("tiny3d.stream")
     bench["per_layer"].append({"name": "edit_pct.tiny", "unit": "%",
                                "better": "lower", "source": "program_span",
                                "layer": "t", "moves": "setup_s",
@@ -116,6 +137,18 @@ def test_a_new_cell_runs_from_added_files_alone(tmp_path):
                            trace_dir=tmp_path / "trace", log=_quiet)
     assert out["correct"] is True, out["checks"]
     assert set(out["metrics"]) == {"edit_pct.tiny"}
+    out = harness.run_cell("tiny3d.stream", SEED, 0.5, False,
+                           require_tpu=False, root=tmp_path, log=_quiet)
+    assert out["correct"] is True, out["checks"]
+    assert out["attempted"] % 3 == 0 and out["failed"] == 0
+    assert set(out["metrics"]) == {"compress_MBps", "bits_per_value",
+                                   "setup_s"}
+    for path in (ROOT / "bench").rglob("*"):
+        rel = path.relative_to(ROOT / "bench")
+        if path.is_file() and rel.parts[0] not in ("tests", "__pycache__") \
+                and path.suffix != ".pyc":
+            assert (tmp_path / "bench" / rel).read_bytes() \
+                == path.read_bytes(), rel
 
 
 def _cli(cwd, env_extra=None):
