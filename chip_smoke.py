@@ -309,7 +309,7 @@ def four_chips(phase: str, shape=NYX_SHAPE, seed: int = SEED,
     fj = jax.device_put(f)
     topo = fixes.field_topology(fj, xi)
     compiled = fixes._fused_fix_impl.lower(
-        fj, topo, max_iters=512, backend=be).compile()
+        fj, topo, max_iters=None, backend=be).compile()
     g, _, _ = compiled(fj, topo)
     g.block_until_ready()
     shard_devices = {s.device.id for s in g.addressable_shards}

@@ -191,7 +191,7 @@ def _member(x_b: jnp.ndarray, i: int) -> jnp.ndarray:
     return x_b[i]
 
 
-def _device_compress(f: np.ndarray, xi: float, be, max_iters: int,
+def _device_compress(f: np.ndarray, xi: float, be, max_iters: Optional[int],
                      edit_value_dtype: str, step: float,
                      entropy: str = "deflate") -> CompressedArtifact:
     """Single host->device transfer of f; transform, reconstruction, fix
@@ -214,7 +214,7 @@ def _device_compress(f: np.ndarray, xi: float, be, max_iters: int,
     g, iters, ok = fixes.fused_fix(f_hat, topo, max_iters=max_iters,
                                    backend=be)
     if not bool(_d2h(ok)):
-        raise RuntimeError("MSz fix loops did not converge within max_iters")
+        raise RuntimeError(fixes.NOT_CONVERGED)
     idx_d, val_d = extract_edits(f_hat, g)
     t2 = time.perf_counter()
 
@@ -318,7 +318,7 @@ def _pull_batch_codes(be, r_b, B: int, entropy: str):
 
 
 def _device_batch_stage(fields: List[np.ndarray], xi_arr: np.ndarray,
-                        be, max_iters: int, steps: List[float],
+                        be, max_iters: Optional[int], steps: List[float],
                         entropy: str = "deflate") -> _DeviceBatch:
     """The device-resident half of a compress batch: ONE h2d of the
     stacked fields, ONE vmapped transform + ONE batched fix loop +
@@ -338,7 +338,7 @@ def _device_batch_stage(fields: List[np.ndarray], xi_arr: np.ndarray,
     g_b, iters_b, ok_b = fixes.fused_fix_batch(fhat_b, topo_b,
                                                max_iters=max_iters, backend=be)
     if not bool(_d2h(jnp.all(ok_b))):
-        raise RuntimeError("MSz fix loops did not converge within max_iters")
+        raise RuntimeError(fixes.NOT_CONVERGED)
     edits = [extract_edits(_member(fhat_b, i), _member(g_b, i))
              for i in range(B)]
     t2 = time.perf_counter()
@@ -400,7 +400,7 @@ def _encode_batch_member(db: _DeviceBatch, i: int,
 
 
 def _device_pipelined_stage(fields: List[np.ndarray], xi_arr: np.ndarray,
-                            be, max_iters: int, steps: List[float],
+                            be, max_iters: Optional[int], steps: List[float],
                             n_real: Optional[int] = None,
                             entropy: str = "deflate") -> _DeviceBatch:
     """The stream scheduler's large-member alternative to
@@ -431,8 +431,7 @@ def _device_pipelined_stage(fields: List[np.ndarray], xi_arr: np.ndarray,
         g, iters, ok = fixes.fused_fix(fhat_i, topo, max_iters=max_iters,
                                        backend=be)
         if not bool(_d2h(ok)):
-            raise RuntimeError(
-                "MSz fix loops did not converge within max_iters")
+            raise RuntimeError(fixes.NOT_CONVERGED)
         edits.append(extract_edits(fhat_i, g))
         iters_list.append(int(_d2h(iters)))
     t2 = time.perf_counter()
@@ -457,7 +456,7 @@ def _device_pipelined_stage(fields: List[np.ndarray], xi_arr: np.ndarray,
 
 
 def _device_compress_batch(fields: List[np.ndarray], xi_arr: np.ndarray,
-                           be, max_iters: int, edit_value_dtype: str,
+                           be, max_iters: Optional[int], edit_value_dtype: str,
                            steps: List[float],
                            entropy: str = "deflate"
                            ) -> List[CompressedArtifact]:
@@ -498,7 +497,7 @@ def _host_compressor(base: str, entropy: str) -> Optional[Callable]:
 def compress_preserving_mss(f: np.ndarray, xi: float, base: BaseName = "szlike",
                             mode: str = "fused",
                             edit_value_dtype: str = "auto",
-                            max_iters: int = 512,
+                            max_iters: Optional[int] = None,
                             backend: BackendLike = "auto",
                             mesh=None,
                             device_path: DevicePath = "auto",
@@ -545,7 +544,7 @@ def compress_preserving_mss_batch(
         xi: Union[float, Sequence[float]],
         base: BaseName = "szlike",
         edit_value_dtype: str = "auto",
-        max_iters: int = 512,
+        max_iters: Optional[int] = None,
         backend: BackendLike = "auto",
         mesh=None,
         device_path: DevicePath = "auto",

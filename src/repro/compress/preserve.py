@@ -38,13 +38,14 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, Mapping, Tuple
+from typing import Callable, Dict, List, Mapping, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.driver import (MszResult, apply_edits, derive_edits,
                            derive_edits_batch, verify_preservation)
+from ..core.fixes import NOT_CONVERGED
 from . import codec, szlike, zfplike
 
 __all__ = [
@@ -326,7 +327,7 @@ def _make_artifact(f: np.ndarray, payload: bytes, blob: bytes, xi: float,
 def compress_host(name: str, f: np.ndarray, xi: float, *,
                   compressor: Callable[..., bytes] = None,
                   mode: str = "fused", edit_value_dtype: str = "auto",
-                  max_iters: int = 512, backend="auto",
+                  max_iters: Optional[int] = None, backend="auto",
                   mesh=None) -> CompressedArtifact:
     """The codec-agnostic host compression path: base round-trip through
     the registered codec ``name`` (or ``compressor``, a pre-bound
@@ -344,7 +345,7 @@ def compress_host(name: str, f: np.ndarray, xi: float, *,
     res = derive_edits(f, f_hat, xi, mode=mode, max_iters=max_iters,
                        backend=backend, mesh=mesh)
     if not res.converged:
-        raise RuntimeError("MSz fix loops did not converge within max_iters")
+        raise RuntimeError(NOT_CONVERGED)
     t2 = time.perf_counter()
     blob = encode_edits_checked(f, f_hat, res, xi, edit_value_dtype)
     return _make_artifact(f, payload, blob, xi, pc.name, res, t1 - t0,
@@ -355,7 +356,7 @@ def compress_host_batch(name: str, fields: List[np.ndarray],
                         xi_arr: np.ndarray, *,
                         compressor: Callable[..., bytes] = None,
                         edit_value_dtype: str = "auto",
-                        max_iters: int = 512, backend="auto",
+                        max_iters: Optional[int] = None, backend="auto",
                         mesh=None) -> List[CompressedArtifact]:
     """Batch form of ``compress_host``: per-member base round-trips, then
     ONE batched fix loop over the stacked members
@@ -383,8 +384,7 @@ def compress_host_batch(name: str, fields: List[np.ndarray],
     for fi, xi_i, payload, f_hat, res, t_base in zip(
             fields, xi_arr, payloads, fhats, results, t_bases):
         if not res.converged:
-            raise RuntimeError(
-                "MSz fix loops did not converge within max_iters")
+            raise RuntimeError(NOT_CONVERGED)
         blob = encode_edits_checked(fi, f_hat, res, float(xi_i),
                                     edit_value_dtype)
         arts.append(_make_artifact(fi, payload, blob, float(xi_i), pc.name,

@@ -178,7 +178,7 @@ class _StreamBase:
                  linger_ms: float = 2.0,
                  backend: BackendLike = "auto", mesh=None,
                  device_path: pipeline.DevicePath = "auto",
-                 max_iters: int = 512,
+                 max_iters: Optional[int] = None,
                  workers: Optional[int] = None,
                  strict_uniform: bool = False,
                  pad_pow2: bool = True,
@@ -253,6 +253,8 @@ class _StreamBase:
         self._t_encode = 0.0                 # guarded-by: self._lock
         self._dispatched = 0                 # guarded-by: self._lock
         self._queue_wait = 0.0               # guarded-by: self._lock
+        self._fix_iters = 0                  # guarded-by: self._lock
+        self._fix_iters_max = 0              # guarded-by: self._lock
         # guarded-by: self._lock
         self._t_first_submit: Optional[float] = None
         # guarded-by: self._lock
@@ -345,11 +347,14 @@ class _StreamBase:
         # counters first (a caller woken by set_result must see them
         # settled), then the result, then the flush()/slot wake-ups —
         # so fut.done() holds by the time flush() returns
+        iters = int(getattr(result, "fix_iters", 0))
         with self._lock:
             if exc is not None:
                 self._failed += 1
             else:
                 self._completed += 1
+                self._fix_iters += iters
+                self._fix_iters_max = max(self._fix_iters_max, iters)
             self._in_flight -= 1
             self._t_last_done = time.perf_counter()
         try:
@@ -501,7 +506,10 @@ class _StreamBase:
         completion; ``batch_occupancy`` is real members / dispatched
         member slots (padding included in the denominator);
         ``queue_wait_s`` sums, over the ``dispatched`` requests the
-        scheduler has popped, the time from submit to pop. ``t_device_s``
+        scheduler has popped, the time from submit to pop.
+        ``fix_iters`` sums the fix-loop passes of the completed compress
+        requests' artifacts, ``fix_iters_max`` is the most any one took
+        (both 0 on the read side). ``t_device_s``
         is host seconds around each batch's device stage (what the
         straggler watchdog observes), not device time."""
         with self._lock:
@@ -531,6 +539,8 @@ class _StreamBase:
                 t_encode_s=self._t_encode,
                 dispatched=self._dispatched,
                 queue_wait_s=self._queue_wait,
+                fix_iters=self._fix_iters,
+                fix_iters_max=self._fix_iters_max,
                 fields_per_sec=(self._completed / elapsed
                                 if elapsed and self._completed else 0.0),
                 fix_modes=dict(self._fix_mode_counts),

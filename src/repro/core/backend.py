@@ -9,8 +9,8 @@ backend exposes the two stencil stages of the fused fix iteration,
   * ``fix_pass(g, topo, masks)`` — the pull-based conflict-free edit
     application (DESIGN.md §2)
 
-plus ``fused_step`` composing them into one (g_next, n_violations)
-iteration, and — since the device-resident compression path
+plus ``fused_step`` composing them into one (g_next, n_violations,
+n_moved) iteration, and — since the device-resident compression path
 (DESIGN.md §4) — the SZ-like base transform pair,
 
   * ``transform(f, step)``          — quantize + integer Lorenzo
@@ -121,10 +121,29 @@ def trouble_masks(g_codes: FalseMasks, topo):
     return trouble_max, trouble_min
 
 
-def _halve_toward_lower(g, lower, mask):
-    """Eq. 2/3/4/5/6 decreasing edit, clamped so |f-g|<=xi holds exactly."""
+def halve_toward_lower(g, lower):
+    """The decreasing edit of Eq. 2/3/4/5/6: halve the way from ``g`` to
+    ``lower``, clamped at ``lower`` so |f-g|<=xi holds exactly. Where the
+    halving rounds back to ``g`` (``g`` one ulp above ``lower`` and the
+    sum's tie rounding to even), the edit goes to ``lower`` instead, so an
+    edit of a value above its bound always moves it (DESIGN.md §2)."""
     new = jnp.maximum((g + lower) * jnp.asarray(0.5, g.dtype), lower)
-    return jnp.where(mask, new, g)
+    return jnp.where(new == g, lower, new)
+
+
+def _halve_toward_lower(g, lower, mask):
+    """``halve_toward_lower`` where ``mask`` is set, ``g`` elsewhere."""
+    return jnp.where(mask, halve_toward_lower(g, lower), g)
+
+
+def keep_going(viol, moved, it, max_iters: Optional[int]):
+    """The fix loops' condition (DESIGN.md §2, "Why the loop ends"): the
+    last pass found violations AND moved a value. A pass with violations
+    that moves nothing would repeat itself forever, so the loop stops
+    there unconverged. ``max_iters`` None means no count cap; a number
+    caps the passes as well."""
+    go = (viol > 0) & (moved > 0)
+    return go if max_iters is None else go & (it < max_iters)
 
 
 def _pull(src_mask: jnp.ndarray, code: jnp.ndarray) -> jnp.ndarray:
@@ -196,14 +215,17 @@ class ReferenceBackend:
 
     def fix_pass(self, g: jnp.ndarray, topo, masks: StencilMasks):
         """Conflict-free pull-based edit application (DESIGN.md §2):
-        (g_next, n_violations)."""
+        (g_next, n_violations, n_moved), the last the count of values
+        the pass changed."""
         target = ((masks.self_edit != 0)
                   | _pull(masks.demote_src != 0, masks.up_c_g)
                   | _pull(masks.promote_src != 0, masks.dn_c_f))
-        return _halve_toward_lower(g, topo.lower, target), masks.n_violations
+        g2 = _halve_toward_lower(g, topo.lower, target)
+        return (g2, masks.n_violations,
+                jnp.sum(g2 != g, dtype=jnp.int32))
 
     def fused_step(self, g: jnp.ndarray, topo):
-        """One fused fix iteration: (g_next, n_violations)."""
+        """One fused fix iteration: (g_next, n_violations, n_moved)."""
         masks = self.extrema_masks(g, topo)
         return self.fix_pass(g, topo, masks)
 
@@ -303,13 +325,15 @@ class PallasBackend:
 
     def fix_pass(self, g: jnp.ndarray, topo, masks: StencilMasks):
         """Pull-based edit application via the slab kernel:
-        (g_next, n_violations)."""
+        (g_next, n_violations, n_moved), the counts summed from the
+        kernel's per-slab source and moved-target counts."""
         from ..kernels.fixpass import fix_pass_pallas
-        g2, viol, _ = fix_pass_pallas(
+        g2, viol, tgt = fix_pass_pallas(
             g, topo.lower, masks.self_edit, masks.demote_src,
             masks.promote_src, masks.up_c_g, masks.dn_c_f,
             interpret=self._interpret())
-        return g2, jnp.sum(viol).astype(jnp.int32)
+        return (g2, jnp.sum(viol).astype(jnp.int32),
+                jnp.sum(tgt).astype(jnp.int32))
 
     # -- fused iteration, tiled when needed ---------------------------
     def _pick_tile(self, n_slabs: int) -> int:
@@ -319,8 +343,8 @@ class PallasBackend:
             else self.vmem_slab_budget
 
     def fused_step(self, g: jnp.ndarray, topo):
-        """One fused fix iteration: (g_next, n_violations), Z-tiled
-        when the field exceeds the VMEM slab budget."""
+        """One fused fix iteration: (g_next, n_violations, n_moved),
+        Z-tiled when the field exceeds the VMEM slab budget."""
         tile = self._pick_tile(g.shape[0])
         if tile >= g.shape[0]:
             masks = self.extrema_masks(g, topo)
@@ -377,7 +401,7 @@ class PallasBackend:
         n = g.shape[0]
         interp = self._interpret()
         outs = []
-        viol = jnp.int32(0)
+        viol = moved = jnp.int32(0)
         for z0 in range(0, n, tile):
             z1 = min(z0 + tile, n)
             a, b = max(z0 - 2, 0), min(z1 + 2, n)
@@ -390,17 +414,19 @@ class PallasBackend:
                 slab_lo=a, n_slabs_total=n)
             c, d = max(z0 - 1, 0), min(z1 + 1, n)
             ss = slice(c - a, d - a)
-            g2, _, _ = fix_pass_pallas(
+            g2, _, tgt = fix_pass_pallas(
                 g[c:d], topo.lower[c:d],
                 masks.self_edit[ss], masks.demote_src[ss],
                 masks.promote_src[ss], masks.up_c_g[ss], topo.dn_c[c:d],
                 interpret=interp, slab_lo=c, n_slabs_total=n)
-            outs.append(g2[z0 - c:z0 - c + (z1 - z0)])
+            keep = slice(z0 - c, z0 - c + (z1 - z0))
+            outs.append(g2[keep])
+            moved = moved + jnp.sum(tgt[keep]).astype(jnp.int32)
             tp = slice(z0 - a, z1 - a)  # tile proper: each slab counted once
             viol = viol + (jnp.sum(masks.self_edit[tp])
                            + jnp.sum(masks.demote_src[tp])
                            + jnp.sum(masks.promote_src[tp])).astype(jnp.int32)
-        return jnp.concatenate(outs, axis=0), viol
+        return jnp.concatenate(outs, axis=0), viol, moved
 
     # -- dirty-slab worklist loop (DESIGN.md §7) -----------------------
     def use_worklist(self, shape: Tuple[int, ...]) -> bool:
@@ -415,21 +441,24 @@ class PallasBackend:
             return bool(self.worklist) and shape[0] >= 2
         return shape[0] >= self.worklist_min_slabs
 
-    def worklist_loop(self, g0: jnp.ndarray, topo, *, max_iters: int):
+    def worklist_loop(self, g0: jnp.ndarray, topo, *,
+                      max_iters: Optional[int] = None):
         """The fused fix loop with per-slab-group early exit: returns
         (g, iters, converged, skipped_slabs), the first three bitwise
         equal to the dense loop's.
 
         Iteration state carries the previous pass's per-slab fix-source
-        and edit-target counts. A group of slabs re-runs the stencils iff
-        any slab within 2 slabs of the group carried an edit target last
+        and moved edit-target counts. A group of slabs re-runs the
+        stencils iff a value moved within 2 slabs of the group last
         iteration; other groups reuse their g slice (unchanged by
         construction) and their stale — still exact — source counts. The
         2-slab radius is the stencil dependency depth: a slab's fix
         output reads masks one slab out, and those masks read g one slab
-        further (DESIGN.md §7 gives the induction). Convergence tests the
-        summed source counts, identical to the dense loop's violation
-        count, so iteration counts match too. ``skipped_slabs``
+        further (DESIGN.md §7 gives the induction). The loop's condition
+        (``keep_going``) reads the summed source counts, identical to the
+        dense loop's violation count, and the summed moved counts, the
+        dense loop's count of changed values, so iteration counts match
+        too. ``skipped_slabs``
         accumulates slabs whose group was skipped, summed over
         iterations (the benchmark's worklist-win metric).
 
@@ -508,8 +537,8 @@ class PallasBackend:
                     tgts.reshape(-1)[:n], skipped)
 
         def cond(state):
-            _, it, src, _, _ = state
-            return (jnp.sum(src) > 0) & (it < max_iters)
+            _, it, src, tgt, _ = state
+            return keep_going(jnp.sum(src), jnp.sum(tgt), it, max_iters)
 
         # first iteration unconditionally runs every group (tgt
         # sentinel 1s), mirroring the dense loop's step-then-while shape

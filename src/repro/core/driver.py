@@ -64,7 +64,7 @@ def _package_result(f, f_hat, g, iters, ok, backend_name: str) -> MszResult:
 
 
 def derive_edits(f, f_hat, xi: float, mode: Mode = "fused",
-                 max_iters: int = 512,
+                 max_iters: Optional[int] = None,
                  backend: BackendLike = "auto", mesh=None) -> MszResult:
     """Compute the edit series {delta_i} such that f_hat + delta has exactly
     the MS segmentation of f, while |f - (f_hat+delta)| <= xi (Section 4).
@@ -96,7 +96,7 @@ def derive_edits(f, f_hat, xi: float, mode: Mode = "fused",
 
 
 def derive_edits_batch(f, f_hat, xi: Union[float, Sequence[float]],
-                       max_iters: int = 512,
+                       max_iters: Optional[int] = None,
                        backend: BackendLike = "auto",
                        mesh=None, batching: str = "auto",
                        compact_every: int = 8) -> List[MszResult]:

@@ -34,7 +34,7 @@ conflict-free by construction and bitwise deterministic (DESIGN.md §2).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,9 +43,14 @@ import numpy as np
 from . import grid
 from .backend import (BackendLike, FalseMasks, StencilMasks,  # noqa: F401
                       _halve_toward_lower, _pull, _device_scalar,
-                      false_critical_masks, get_backend, resolve_backend,
-                      trouble_masks)
+                      false_critical_masks, get_backend, keep_going,
+                      resolve_backend, trouble_masks)
 from .labels import labels_from_codes, pointer_jump
+
+
+#: what a caller raises when a fix loop ends unconverged (DESIGN.md §2)
+NOT_CONVERGED = ("MSz fix loops did not converge: a pass with violations "
+                 "moved no value, or max_iters was reached")
 
 
 class FieldTopo(NamedTuple):
@@ -86,7 +91,7 @@ def fused_pass(g: jnp.ndarray, topo: FieldTopo,
     Returns (g_next, n_violations). n_violations == 0 iff g already
     preserves the full MS segmentation of f (extrema + all labels).
     """
-    return get_backend(backend).fused_step(g, topo)
+    return get_backend(backend).fused_step(g, topo)[:2]
 
 
 def _bind(be):
@@ -95,9 +100,15 @@ def _bind(be):
     return be.bind() if hasattr(be, "bind") else be
 
 
+def _moved(g2, g) -> jnp.ndarray:
+    """How many values a paper-mode pass moved (int32). The fused loops
+    take the count from ``fused_step``."""
+    return jnp.sum(g2 != g, dtype=jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("max_iters", "backend"))
-def _fused_fix_impl(g0: jnp.ndarray, topo: FieldTopo, max_iters: int,
-                    backend):
+def _fused_fix_impl(g0: jnp.ndarray, topo: FieldTopo,
+                    max_iters: Optional[int], backend):
     be = backend
     if hasattr(be, "fix_loop"):
         # distributed backends run the whole loop inside one shard_map
@@ -110,22 +121,29 @@ def _fused_fix_impl(g0: jnp.ndarray, topo: FieldTopo, max_iters: int,
         return g, iters, ok
 
     def cond(state):
-        g, it, viol = state
-        return (viol > 0) & (it < max_iters)
+        g, it, viol, moved = state
+        return keep_going(viol, moved, it, max_iters)
 
     def body(state):
-        g, it, _ = state
-        g2, viol2 = be.fused_step(g, topo)
-        return g2, it + 1, viol2
+        g, it, _, _ = state
+        g2, viol2, moved2 = be.fused_step(g, topo)
+        return g2, it + 1, viol2, moved2
 
-    g1, viol1 = be.fused_step(g0, topo)
-    g, iters, viol = jax.lax.while_loop(cond, body, (g1, jnp.int32(1), viol1))
+    g1, viol1, moved1 = be.fused_step(g0, topo)
+    g, iters, viol, _ = jax.lax.while_loop(
+        cond, body, (g1, jnp.int32(1), viol1, moved1))
     return g, iters, viol == 0
 
 
-def fused_fix(g0: jnp.ndarray, topo: FieldTopo, max_iters: int = 512,
+def fused_fix(g0: jnp.ndarray, topo: FieldTopo,
+              max_iters: Optional[int] = None,
               backend: BackendLike = "auto", mesh=None):
     """Run the fused loop to convergence. Returns (g, iters, converged).
+
+    The loop ends when a pass finds no violation (converged) or when a
+    pass with violations moves no value (not converged; DESIGN.md §2
+    says when that can happen). ``max_iters`` None sets no cap on the
+    passes; a number caps them too.
 
     ``backend`` selects the stencil execution strategy (see
     core.backend); all backends produce bitwise-identical trajectories,
@@ -138,13 +156,13 @@ def fused_fix(g0: jnp.ndarray, topo: FieldTopo, max_iters: int = 512,
 
 
 @functools.partial(jax.jit, static_argnames=("max_iters", "backend"))
-def _worklist_fix_impl(g0: jnp.ndarray, topo: FieldTopo, max_iters: int,
-                       backend):
+def _worklist_fix_impl(g0: jnp.ndarray, topo: FieldTopo,
+                       max_iters: Optional[int], backend):
     return backend.worklist_loop(g0, topo, max_iters=max_iters)
 
 
 def fused_fix_worklist(g0: jnp.ndarray, topo: FieldTopo,
-                       max_iters: int = 512,
+                       max_iters: Optional[int] = None,
                        backend: BackendLike = "pallas_worklist", mesh=None):
     """Run the fused loop through a backend's dirty-slab worklist driver
     (DESIGN.md §7), regardless of its auto-engage threshold. Returns
@@ -161,31 +179,40 @@ def fused_fix_worklist(g0: jnp.ndarray, topo: FieldTopo,
     return _worklist_fix_impl(g0, topo, max_iters=max_iters, backend=be)
 
 
-@functools.partial(jax.jit, static_argnames=("max_iters", "backend"))
-def _fused_fix_batch_impl(g0: jnp.ndarray, topo: FieldTopo, max_iters: int,
-                          backend):
-    be = backend
-    step = jax.vmap(be.fused_step, in_axes=(0, 0))
-
+def _batched_loop(step, g, topo, it0, iters_b, viol, moved, n_iters):
+    """The vmapped fused loop: each member steps while ``keep_going``
+    holds for it, to at most ``n_iters`` passes of the loop (None: no
+    cap). A member that stops is frozen (its fix-target set is empty, or
+    nothing moved, so ``g2 == g`` for it already; the ``where`` is
+    belt-and-braces), so its (g, iters) stay bitwise equal to a solo
+    run's. Returns (g, iters_b, viol, moved)."""
     def cond(state):
-        g, it, iters_b, viol = state
-        return jnp.any(viol > 0) & (it < max_iters)
+        _, it, _, viol, moved = state
+        return jnp.any(keep_going(viol, moved, it, n_iters))
 
     def body(state):
-        g, it, iters_b, viol = state
-        g2, viol2 = step(g, topo)
-        active = viol > 0
-        # a converged member has no fix targets, so g2 == g for it already;
-        # the where is belt-and-braces freezing
+        g, it, iters_b, viol, moved = state
+        g2, viol2, moved2 = step(g, topo)
+        active = keep_going(viol, moved, it, n_iters)
         keep = active.reshape((-1,) + (1,) * (g.ndim - 1))
         return (jnp.where(keep, g2, g), it + 1,
                 iters_b + active.astype(jnp.int32),
-                jnp.where(active, viol2, viol))
+                jnp.where(active, viol2, viol),
+                jnp.where(active, moved2, moved))
 
-    g1, viol1 = step(g0, topo)
+    g, _, iters_b, viol, moved = jax.lax.while_loop(
+        cond, body, (g, it0, iters_b, viol, moved))
+    return g, iters_b, viol, moved
+
+
+@functools.partial(jax.jit, static_argnames=("max_iters", "backend"))
+def _fused_fix_batch_impl(g0: jnp.ndarray, topo: FieldTopo,
+                          max_iters: Optional[int], backend):
+    step = jax.vmap(backend.fused_step, in_axes=(0, 0))
+    g1, viol1, moved1 = step(g0, topo)
     iters0 = jnp.ones(g0.shape[0], jnp.int32)
-    g, _, iters_b, viol = jax.lax.while_loop(
-        cond, body, (g1, jnp.int32(1), iters0, viol1))
+    g, iters_b, viol, _ = _batched_loop(step, g1, topo, jnp.int32(1), iters0,
+                                        viol1, moved1, max_iters)
     return g, iters_b, viol == 0
 
 
@@ -197,38 +224,23 @@ def _pow2_at_least(n: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("k", "backend"))
 def _fused_fix_round_impl(g0: jnp.ndarray, topo: FieldTopo,
-                          viol0: jnp.ndarray, k: int, backend):
+                          viol0: jnp.ndarray, moved0: jnp.ndarray, k: int,
+                          backend):
     """Up to ``k`` iterations of the vmapped fused loop on one compaction
-    bucket. ``viol0`` is each member's violation count entering the round
-    (the first round passes a 1-sentinel so every member takes the dense
-    loop's unconditional first step); members whose count hits 0 freeze,
-    exactly as in ``_fused_fix_batch_impl``, so per-member trajectories
-    stay bitwise equal to solo runs. Returns (g, iters_this_round, viol).
-    """
-    be = backend
-    step = jax.vmap(be.fused_step, in_axes=(0, 0))
-
-    def cond(state):
-        _, it, _, viol = state
-        return jnp.any(viol > 0) & (it < k)
-
-    def body(state):
-        g, it, iters_b, viol = state
-        g2, viol2 = step(g, topo)
-        active = viol > 0
-        keep = active.reshape((-1,) + (1,) * (g.ndim - 1))
-        return (jnp.where(keep, g2, g), it + 1,
-                iters_b + active.astype(jnp.int32),
-                jnp.where(active, viol2, viol))
-
+    bucket. ``viol0`` / ``moved0`` are each member's violation and moved
+    counts entering the round (the first round passes 1-sentinels so
+    every member takes the dense loop's unconditional first step);
+    members that stop freeze, exactly as in ``_fused_fix_batch_impl``, so
+    per-member trajectories stay bitwise equal to solo runs. Returns (g,
+    iters_this_round, viol, moved)."""
+    step = jax.vmap(backend.fused_step, in_axes=(0, 0))
     iters0 = jnp.zeros(g0.shape[0], jnp.int32)
-    g, _, iters_b, viol = jax.lax.while_loop(
-        cond, body, (g0, jnp.int32(0), iters0, viol0))
-    return g, iters_b, viol
+    return _batched_loop(step, g0, topo, jnp.int32(0), iters0, viol0,
+                         moved0, k)
 
 
 def _fused_fix_batch_compact(g0: jnp.ndarray, topo: FieldTopo,
-                             max_iters: int, be, every: int):
+                             max_iters: Optional[int], be, every: int):
     """Active-member compaction driver: the batched loop in host-driven
     rounds of ``every`` iterations, with still-active members gathered
     into a dense prefix between rounds so converged members stop costing
@@ -241,12 +253,13 @@ def _fused_fix_batch_compact(g0: jnp.ndarray, topo: FieldTopo,
     """
     B = g0.shape[0]
     g = g0
-    viol = np.ones(B, np.int32)        # 1-sentinel: everyone steps once
+    viol = np.ones(B, np.int32)        # 1-sentinels: everyone steps once
+    moved = np.ones(B, np.int32)
     iters = np.zeros(B, np.int32)
     active = np.arange(B)
     it_done = 0
-    while active.size and it_done < max_iters:
-        k = min(every, max_iters - it_done)
+    while active.size and (max_iters is None or it_done < max_iters):
+        k = every if max_iters is None else min(every, max_iters - it_done)
         n = active.size
         cap = _pow2_at_least(n)
         # gather padding repeats active[0]; the scatter-back pads with B
@@ -255,25 +268,31 @@ def _fused_fix_batch_compact(g0: jnp.ndarray, topo: FieldTopo,
         sel = np.concatenate([active, np.full(cap - n, active[0],
                                               active.dtype)])
         scat = np.concatenate([active, np.full(cap - n, B, active.dtype)])
-        viol_a0 = np.concatenate([viol[active], np.zeros(cap - n, np.int32)])
-        g, dit_a, viol_a = _compact_round(
+        pad0 = np.zeros(cap - n, np.int32)
+        viol_a0 = np.concatenate([viol[active], pad0])
+        moved_a0 = np.concatenate([moved[active], pad0])
+        g, dit_a, viol_a, moved_a = _compact_round(
             g, topo, jax.device_put(sel), jax.device_put(scat),
-            jax.device_put(viol_a0), k=k, backend=be)
+            jax.device_put(viol_a0), jax.device_put(moved_a0), k=k,
+            backend=be)
         # host sync: one small explicit pull per round (cap-padded)
         dit = jax.device_get(dit_a)[:n]
         viol_n = jax.device_get(viol_a)[:n]
+        moved_n = jax.device_get(moved_a)[:n]
         # mszlint: disable=scatter-discipline -- active is a flatnonzero
         # subset, unique by construction
         iters[active] += dit
         viol[active] = viol_n
+        moved[active] = moved_n
         it_done += k
-        active = active[viol_n > 0]
+        active = active[(viol_n > 0) & (moved_n > 0)]
     return g, jax.device_put(iters), jax.device_put(viol == 0)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "backend"))
 def _compact_round(g: jnp.ndarray, topo: FieldTopo, sel: jnp.ndarray,
-                   scat: jnp.ndarray, viol_a: jnp.ndarray, k: int, backend):
+                   scat: jnp.ndarray, viol_a: jnp.ndarray,
+                   moved_a: jnp.ndarray, k: int, backend):
     """One compaction round, fully jitted: gather the padded active
     bucket, run up to ``k`` iterations, scatter results back (padding
     lanes carry out-of-bounds indices and drop). Keeping the gather/
@@ -282,12 +301,13 @@ def _compact_round(g: jnp.ndarray, topo: FieldTopo, sel: jnp.ndarray,
     ``debug.no_transfers()``."""
     g_a = jnp.take(g, sel, axis=0)
     topo_a = jax.tree_util.tree_map(lambda x: jnp.take(x, sel, axis=0), topo)
-    g_a, dit_a, viol_a = _fused_fix_round_impl(g_a, topo_a, viol_a,
-                                               k=k, backend=backend)
-    return g.at[scat].set(g_a, mode="drop"), dit_a, viol_a
+    g_a, dit_a, viol_a, moved_a = _fused_fix_round_impl(
+        g_a, topo_a, viol_a, moved_a, k=k, backend=backend)
+    return g.at[scat].set(g_a, mode="drop"), dit_a, viol_a, moved_a
 
 
-def fused_fix_batch(g0: jnp.ndarray, topo: FieldTopo, max_iters: int = 512,
+def fused_fix_batch(g0: jnp.ndarray, topo: FieldTopo,
+                    max_iters: Optional[int] = None,
                     backend: BackendLike = "auto", mesh=None,
                     batching: str = "auto", compact_every: int = 8):
     """Batched fused loop over a leading batch axis (many-field workloads:
@@ -373,19 +393,21 @@ def _subloop(g, topo, which: str, max_iters):
                     fpmin=lambda fm: fm.fpmin, fnmax=lambda fm: fm.fnmax)[which]
 
     def cond(state):
-        g, it, n = state
-        return (n > 0) & (it < max_iters)
+        g, it, n, moved = state
+        return keep_going(n, moved, it, max_iters)
 
     def body(state):
-        g, it, _ = state
+        g, it, _, _ = state
         fm = masks(g)
         g2 = _halve_toward_lower(g, topo.lower, target_of(fm))
         fm2 = masks(g2)
-        return g2, it + 1, jnp.sum(count_of(fm2)).astype(jnp.int32)
+        return (g2, it + 1, jnp.sum(count_of(fm2)).astype(jnp.int32),
+                _moved(g2, g))
 
     fm0 = masks(g)
     n0 = jnp.sum(count_of(fm0)).astype(jnp.int32)
-    g, it, _ = jax.lax.while_loop(cond, body, (g, jnp.int32(0), n0))
+    g, it, _, _ = jax.lax.while_loop(cond, body,
+                                     (g, jnp.int32(0), n0, jnp.int32(1)))
     return g, it
 
 
@@ -398,16 +420,18 @@ def _c_loop(g, topo, max_iters):
                 + jnp.sum(fm.fnmax) + jnp.sum(fm.fnmin)).astype(jnp.int32)
 
     def cond(state):
-        g, it, n = state
-        return (n > 0) & (it < max_iters)
+        g, it, n, moved = state
+        return keep_going(n, moved, it, max_iters)
 
     def body(state):
-        g, it, _ = state
+        g0, it, _, _ = state
+        g = g0
         for which in ("fpmax", "fpmin", "fnmax", "fnmin"):
             g, _ = _subloop(g, topo, which, max_iters)
-        return g, it + 1, n_false(g)
+        return g, it + 1, n_false(g), _moved(g, g0)
 
-    g, it, _ = jax.lax.while_loop(cond, body, (g, jnp.int32(0), n_false(g)))
+    g, it, _, _ = jax.lax.while_loop(
+        cond, body, (g, jnp.int32(0), n_false(g), jnp.int32(1)))
     return g
 
 
@@ -431,21 +455,24 @@ def _r_pass(g, topo):
 
 
 @functools.partial(jax.jit, static_argnames=("max_iters",))
-def paper_fix(g0: jnp.ndarray, topo: FieldTopo, max_iters: int = 512):
+def paper_fix(g0: jnp.ndarray, topo: FieldTopo,
+              max_iters: Optional[int] = None):
     """Alternate C- and R-loops until no false critical/regular point
-    (Section 5.3). Returns (g, outer_iters, converged)."""
+    (Section 5.3). Returns (g, outer_iters, converged). Every loop stops
+    as the fused one does (``keep_going``)."""
     def cond(state):
-        g, it, n = state
-        return (n > 0) & (it < max_iters)
+        g, it, n, moved = state
+        return keep_going(n, moved, it, max_iters)
 
     def body(state):
-        g, it, _ = state
-        g = _c_loop(g, topo, max_iters)
+        g_in, it, _, _ = state
+        g = _c_loop(g_in, topo, max_iters)
         g, n_wrong = _r_pass(g, topo)
         fm = false_critical_masks(g, topo)
         n = (n_wrong + jnp.sum(fm.fpmax) + jnp.sum(fm.fpmin)
              + jnp.sum(fm.fnmax) + jnp.sum(fm.fnmin)).astype(jnp.int32)
-        return g, it + 1, n
+        return g, it + 1, n, _moved(g, g_in)
 
-    g, iters, n = jax.lax.while_loop(cond, body, (g0, jnp.int32(0), jnp.int32(1)))
+    g, iters, n, _ = jax.lax.while_loop(
+        cond, body, (g0, jnp.int32(0), jnp.int32(1), jnp.int32(1)))
     return g, iters, n == 0

@@ -49,15 +49,19 @@ def pointer_jump(nxt: jnp.ndarray,
     if max_iters is None:
         max_iters = default_pointer_iters(nxt.size)
 
+    # the state carries the next sweep's pointers beside the current
+    # ones, so each sweep gathers once: the test for a change and the
+    # step share one gather
     def cond(state):
-        it, cur = state
-        return (it < max_iters) & jnp.any(cur != jnp.take(cur, cur))
+        it, cur, nxt2 = state
+        return (it < max_iters) & jnp.any(cur != nxt2)
 
     def body(state):
-        it, cur = state
-        return it + 1, jnp.take(cur, cur)
+        it, _, nxt2 = state
+        return it + 1, nxt2, jnp.take(nxt2, nxt2)
 
-    _, out = jax.lax.while_loop(cond, body, (jnp.int32(0), nxt))
+    _, out, _ = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), nxt, jnp.take(nxt, nxt)))
     return out
 
 

@@ -71,7 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec
 
-from ..core.backend import register_backend
+from ..core.backend import keep_going, register_backend
 from ..kernels.extrema import (default_interpret, extrema_masks_pallas,
                                typed_operand)
 from ..kernels.fixpass import fix_pass_pallas
@@ -699,11 +699,21 @@ def _step_overlap(g_loc, topo_ext2, run, src, *, plan: BlockPlan,
     return g2_loc, viol, src2, run2
 
 
+def _moved_count(g2_loc, g_loc, plan: BlockPlan) -> jnp.ndarray:
+    """How many real (unpadded) values one pass changed, summed over
+    every sharded axis: the loop's progress count (DESIGN.md §2)."""
+    real = _real_weight(plan, _origins(plan))
+    n = jnp.sum((g2_loc != g_loc) * real).astype(jnp.int32)
+    names = plan.axis_names()
+    return jax.lax.psum(n, names) if names else n
+
+
 # ---------------------------------------------------------------------------
 # full distributed loop (one shard_map around the whole while_loop)
 # ---------------------------------------------------------------------------
 
-def sharded_fix(g0: jnp.ndarray, topo, mesh: Mesh, *, max_iters: int = 512,
+def sharded_fix(g0: jnp.ndarray, topo, mesh: Mesh, *,
+                max_iters: Optional[int] = None,
                 axis_name: Optional[str] = None,
                 interpret: Optional[bool] = None,
                 worklist: Optional[bool] = None,
@@ -717,7 +727,9 @@ def sharded_fix(g0: jnp.ndarray, topo, mesh: Mesh, *, max_iters: int = 512,
     group), only ``g`` — and, without overlap, mask — faces move per
     iteration, and the convergence predicate is the violation count
     psummed over every sharded axis, replicated so all devices decide
-    identically.
+    identically. So is the count of values the pass moved: the loop
+    stops where ``core.backend.keep_going`` says, as the single-device
+    loops do.
 
     ``worklist`` (default on with >= 2 vertices per sharded block axis)
     is the per-block dirty skip of PR 6; ``overlap`` (default on for
@@ -734,6 +746,7 @@ def sharded_fix(g0: jnp.ndarray, topo, mesh: Mesh, *, max_iters: int = 512,
     topo_p = jax.tree_util.tree_map(lambda x: _pad_blocks(x, plan), topo)
     depth = 2 if use_overlap else 1
     block = plan.block_shape()
+    moved = functools.partial(_moved_count, plan=plan)
 
     def spmd(g_loc, topo_loc):
         topo_ext = exchange_tree(topo_loc, plan, depth)
@@ -742,28 +755,29 @@ def sharded_fix(g0: jnp.ndarray, topo, mesh: Mesh, *, max_iters: int = 512,
                                      interpret=interpret, worklist=use_wl)
 
             def body(state):
-                g, it, _, src, run = state
+                g, it, _, _, src, run = state
                 g2, viol2, src2, run2 = step(g, topo_ext, run, src)
-                return g2, it + 1, viol2, src2, run2
+                return g2, it + 1, viol2, moved(g2, g), src2, run2
         else:
             step_p = functools.partial(_step_plain, plan=plan,
                                        interpret=interpret, worklist=use_wl)
 
             def body(state):
-                g, it, _, src, run, cache = state
+                g, it, _, _, src, run, cache = state
                 g2, viol2, src2, cache2, run2 = step_p(
                     g, topo_ext, run, src, cache)
-                return g2, it + 1, viol2, src2, run2, cache2
+                return g2, it + 1, viol2, moved(g2, g), src2, run2, cache2
 
         def cond(state):
-            return (state[2] > 0) & (state[1] < max_iters)
+            return keep_going(state[2], state[3], state[1], max_iters)
 
         run0 = jnp.bool_(True)
         src0 = jnp.int32(0)
         if use_overlap:
             g1, viol1, src1, run1 = step(g_loc, topo_ext, run0, src0)
             out = jax.lax.while_loop(
-                cond, body, (g1, jnp.int32(1), viol1, src1, run1))
+                cond, body, (g1, jnp.int32(1), viol1, moved(g1, g_loc),
+                             src1, run1))
         else:
             cache0 = tuple(
                 (jnp.zeros((4,) + tuple(1 if d == a.dim else s
@@ -775,7 +789,8 @@ def sharded_fix(g0: jnp.ndarray, topo, mesh: Mesh, *, max_iters: int = 512,
             g1, viol1, src1, cache1, run1 = step_p(
                 g_loc, topo_ext, run0, src0, cache0)
             out = jax.lax.while_loop(
-                cond, body, (g1, jnp.int32(1), viol1, src1, run1, cache1))
+                cond, body, (g1, jnp.int32(1), viol1, moved(g1, g_loc),
+                             src1, run1, cache1))
         return out[0], out[1], out[2]
 
     spec = plan.spec()
@@ -1099,16 +1114,18 @@ class ShardedBackend:
             g2, viol, _, _, _ = _step_plain(
                 g_loc, topo_ext, jnp.bool_(True), jnp.int32(0), (),
                 plan=plan, interpret=be._interpret(), worklist=False)
-            return g2, viol
+            return g2, viol, _moved_count(g2, g_loc, plan)
 
         spec = plan.spec()
-        g2, viol = jax.shard_map(
+        g2, viol, moved = jax.shard_map(
             spmd, mesh=be.mesh, in_specs=(spec, spec),
-            out_specs=(spec, PartitionSpec()), check_vma=False)(g_p, topo_p)
-        return _unpad(g2, plan), viol
+            out_specs=(spec, PartitionSpec(), PartitionSpec()),
+            check_vma=False)(g_p, topo_p)
+        return _unpad(g2, plan), viol, moved
 
     # -- full-loop fast path consumed by fixes.fused_fix ---------------
-    def fix_loop(self, g0: jnp.ndarray, topo, max_iters: int = 512):
+    def fix_loop(self, g0: jnp.ndarray, topo,
+                 max_iters: Optional[int] = None):
         """The whole fused loop inside ONE shard_map (one topology
         halo exchange, per-iteration face exchanges): (g, iters,
         converged), bitwise equal to the single-device loop."""

@@ -2,7 +2,8 @@
 application (paper Section 6.1, atomicCAS replaced by a gather + min
 reduction; see DESIGN.md §2).
 
-Each vertex j decreases to (g_j + lower_j)/2 iff
+Each vertex j decreases to (g_j + lower_j)/2 (to lower_j where that
+rounds back to g_j) iff
   * j is its own fix target (self_edit[j]), or
   * a stencil neighbor i has demote_src[i] and up_code_g[i] pointing at j, or
   * a stencil neighbor i has promote_src[i] and dn_code_f[i] pointing at j.
@@ -12,7 +13,8 @@ with a row halo), including the global-coordinate placement for tiled
 execution. Also emits per-slab violation (fix-source) and edit-target
 counts — one per z-slab in 3D, one per row in 2D: the source counts are
 the paper's lock-free work-queue height turned into a reduction, and
-the target counts are the dirty-slab bitmap the worklist drivers
+the target counts (targets whose value moved) are the dirty-slab bitmap
+the worklist drivers
 (DESIGN.md §7) use to skip slabs whose neighborhood did not change last
 pass. The counts leave each program as an (S, 1) column block (S slabs
 or rows per program), which meets Mosaic's tiling rule at any S; masks
@@ -68,6 +70,10 @@ def _kernel(origin_c, g_c, low_c, self_c,
     g = plane(g_c)
     low = plane(low_c)
     new = jnp.maximum((g + low) * jnp.asarray(0.5, g.dtype), low)
+    # a halving that rounds back to g goes to the bound (core.backend
+    # .halve_toward_lower), so every edit of a value above it moves it
+    new = jnp.where(new == g, low, new)
+    moved = jnp.where((target != 0) & (new != g), 1, 0)
     g_out[...] = jnp.where(target != 0, new, g).reshape(g_out.shape)
 
     def counts(x):
@@ -79,7 +85,7 @@ def _kernel(origin_c, g_c, low_c, self_c,
         return rows.reshape(viol_out.shape)
 
     viol_out[...] = counts(self_p + dem[1] + pro[1])
-    tgt_out[...] = counts(target)
+    tgt_out[...] = counts(moved)
 
 
 def fix_pass_pallas(g, lower, self_edit, demote_src, promote_src,
@@ -90,12 +96,14 @@ def fix_pass_pallas(g, lower, self_edit, demote_src, promote_src,
                     n_cols_total: int | None = None):
     """Apply one fused fix pass. All inputs (Z,Y,X) or (Y,X); masks int32
     0/1. Returns (g_next of g's shape/dtype, viol (n,) int32 per-slab
-    fix-SOURCE counts, tgt (n,) int32 per-slab edit-TARGET counts), n
-    being the leading extent (z-slabs in 3D, rows in 2D). ``viol``
-    drives convergence (0 sources everywhere == done); ``tgt`` feeds the
-    dirty-slab worklists (DESIGN.md §7): a slab whose targets were 0
-    last pass — and whose 2-slab neighborhood's were too — produces
-    bitwise-identical masks this pass and can be skipped.
+    fix-SOURCE counts, tgt (n,) int32 per-slab counts of edit TARGETS
+    whose value moved), n being the leading extent (z-slabs in 3D, rows
+    in 2D). ``viol`` drives convergence (0 sources everywhere == done);
+    ``tgt`` is the loop's progress (sources with no moved value is a
+    stall, DESIGN.md §2) and feeds the dirty-slab worklists (DESIGN.md
+    §7): a slab where nothing moved last pass — nor in its 2-slab
+    neighborhood — produces bitwise-identical masks this pass and can be
+    skipped.
     ``slab_lo``/``n_slabs_total`` and ``row_lo``/``col_lo`` with
     ``n_rows_total``/``n_cols_total`` as in the extrema kernel (offsets
     may be traced; the matching total is then required)."""

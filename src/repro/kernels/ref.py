@@ -34,8 +34,7 @@ def fix_pass_ref(g, lower, self_edit, demote_src, promote_src,
     target = ((self_edit != 0)
               | fixes._pull(demote_src != 0, up_code_g)
               | fixes._pull(promote_src != 0, dn_code_f))
-    new = jnp.maximum((g + lower) * 0.5, lower)
-    g2 = jnp.where(target, new, g)
+    g2 = fixes._halve_toward_lower(g, lower, target)
     viol = (jnp.sum(self_edit) + jnp.sum(demote_src)
             + jnp.sum(promote_src)).astype(jnp.int32)
     return g2, viol

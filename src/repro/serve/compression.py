@@ -103,7 +103,7 @@ class ServiceConfig:
     backend: BackendLike = "auto"
     mesh: Optional[object] = None
     device_path: pipeline.DevicePath = "auto"
-    max_iters: int = 512
+    max_iters: Optional[int] = None
     workers: Optional[int] = None
     cache_size: int = 32
     pad_pow2: bool = True
@@ -238,8 +238,9 @@ class CompressionService:
         """The service stats document (what the HTTP endpoint serves):
         uptime plus one ``repro.compress.stream`` counter snapshot per
         direction — fields/sec, batch occupancy, in-flight depth,
-        transfer bytes, spec-cache hit/miss/eviction counts, the
-        straggler policy's live coalescing scale, and per-mesh-axis
+        fix-loop passes (``fix_iters``, ``fix_iters_max``), transfer
+        bytes, spec-cache hit/miss/eviction counts, the straggler
+        policy's live coalescing scale, and per-mesh-axis
         halo-exchange bytes for sharded dispatches. ``shard_timings``
         carries the cached interior/boundary probe when one has run
         (``shard_timings()`` or ``GET /stats?probe=1`` triggers it)."""

@@ -130,13 +130,15 @@ def test_sharded_fix_direct_entrypoint():
 
 def test_single_step_parity():
     """One fused_step through the protocol: sharded == pallas, including
-    the violation count (the convergence predicate)."""
+    the violation and moved counts (the loop's condition)."""
     mesh = _mesh_or_skip(4)
     f, fh, xi, topo, _, _ = _solo_results((13, 6, 7))
-    g2_p, v_p = get_backend("pallas").fused_step(jnp.asarray(fh), topo)
-    g2_s, v_s = ShardedBackend(mesh=mesh).fused_step(jnp.asarray(fh), topo)
+    g2_p, v_p, m_p = get_backend("pallas").fused_step(jnp.asarray(fh), topo)
+    g2_s, v_s, m_s = ShardedBackend(mesh=mesh).fused_step(jnp.asarray(fh),
+                                                          topo)
     np.testing.assert_array_equal(np.asarray(g2_p), np.asarray(g2_s))
     assert int(v_p) == int(v_s)
+    assert int(m_p) == int(m_s) == int(np.sum(np.asarray(g2_p) != fh))
 
 
 # ---------------------------------------------------------------------------

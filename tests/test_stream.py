@@ -315,6 +315,21 @@ def test_service_roundtrip_and_stats():
     assert st["config"]["window"] == 4
 
 
+def test_service_stats_count_fix_passes():
+    fields, xis, refs = _solo_artifacts(SHAPE_3D, 3)
+    with CompressionService(ServiceConfig(window=4, max_batch=2)) as svc:
+        st0 = svc.stats()["compress"]
+        arts = [svc.submit_compress(f, xi).result()
+                for f, xi in zip(fields, xis)]
+        svc.decompress(arts[0])
+        svc.flush()
+        st = svc.stats()
+    assert st0["fix_iters"] == 0 and st0["fix_iters_max"] == 0
+    assert st["compress"]["fix_iters"] == sum(a.fix_iters for a in refs)
+    assert st["compress"]["fix_iters_max"] == max(a.fix_iters for a in refs)
+    assert st["decompress"]["fix_iters"] == 0
+
+
 def test_service_stats_http_endpoint():
     fields, xis, _ = _solo_artifacts(SHAPE_3D, 1)
     with CompressionService(ServiceConfig(window=2)) as svc:
